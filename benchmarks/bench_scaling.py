@@ -20,10 +20,10 @@ _CHILD = textwrap.dedent("""
     jax.config.update("jax_enable_x64", True)
     from repro.core import mps as M, parallel as PP, sampler as S
     from repro.launch import hloanalysis as H
-    from repro.launch.mesh import make_host_mesh
+    from repro.launch.mesh import make_host_mesh, make_mesh
 
     p = __P__
-    mesh = jax.make_mesh((__P__,), ("data",))
+    mesh = make_mesh((__P__,), ("data",))
     mps = M.random_linear_mps(jax.random.key(0), 8, 64, 3, dtype=jnp.float32)
     n = 256 * p                     # weak scaling: 256 samples per shard
 

@@ -26,7 +26,8 @@ _CHILD = textwrap.dedent("""
 
     scheme = "__SCHEME__"
     p2 = __P2__
-    mesh = jax.make_mesh((1, p2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, p2), ("data", "model"))
     SITES, CHI, D, N = 8, 128, 3, 512
     mps = M.random_linear_mps(jax.random.key(0), SITES, CHI, D,
                               dtype=jnp.float32)

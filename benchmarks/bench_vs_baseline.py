@@ -24,7 +24,8 @@ _CHILD = textwrap.dedent("""
     SITES, CHI, D, N = 8, 96, 3, 640
     mps = M.random_linear_mps(jax.random.key(0), SITES, CHI, D,
                               dtype=jnp.float32)
-    mesh = jax.make_mesh((8,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8,), ("data",))
 
     def timed(make):
         fn = jax.jit(lambda g, lam: make(M.MPS(g, lam, "linear")))

@@ -90,6 +90,8 @@ def run_child(code: str, devices: int = 8, timeout: int = 600) -> dict:
     import subprocess
     import sys
 
+    from repro.runtime.transport import refuse_spawn_on_chip
+    refuse_spawn_on_chip("run_child")
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     root = os.path.join(os.path.dirname(__file__), "..")

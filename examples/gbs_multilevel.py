@@ -21,6 +21,7 @@ import numpy as np  # noqa: E402
 from repro import api  # noqa: E402
 from repro.core import dynamic_bond as DB  # noqa: E402
 from repro.core import mps as M  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 
 def main() -> None:
@@ -29,7 +30,7 @@ def main() -> None:
     key = jax.random.key(1)
 
     # 2 data groups × 4-way tensor parallel over χ (paper Fig. 4)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     print(f"mesh: {dict(mesh.shape)}")
 
     # scheme=AUTO lets the Eq. 7 overhead selector pick single- vs

@@ -58,7 +58,9 @@ field                   meaning
                         from an in-memory MPS (default: temp dir)
 ``checkpoint_dir``      per-segment checkpoint directory (streamed backend)
 ``checkpoint_every``    segments between checkpoints (0 = off)
-``hardware``            perfmodel :class:`Hardware` the AUTO fields plan for
+``hardware``            perfmodel :class:`Hardware` the AUTO fields plan for;
+                        None = the ``perfmodel.PEAKS`` row of this
+                        process's device kind
 ``device_budget``       device memory budget override in bytes
 ======================  =====================================================
 """
@@ -72,8 +74,8 @@ import numpy as np
 from repro.api.runtime import ClusterRuntime, resolve_runtime
 from repro.core.dynamic_bond import stages_from_profile
 from repro.core.parallel import ParallelConfig
-from repro.core.perfmodel import (Hardware, TPU_V5E, Workload,
-                                  choose_tp_scheme)
+from repro.core.perfmodel import (Hardware, Workload, choose_tp_scheme,
+                                  hardware_for)
 from repro.core.sampler import SamplerConfig as CoreSamplerConfig
 from repro.workloads.clamp import normalize_clamp, validate_clamp
 
@@ -115,7 +117,7 @@ class SamplerConfig:
     checkpoint_dir: Optional[str] = None
     checkpoint_every: int = 1
     # planner inputs for the AUTO fields
-    hardware: Hardware = TPU_V5E
+    hardware: Optional[Hardware] = None
     device_budget: Optional[float] = None
 
     def __post_init__(self):
@@ -160,6 +162,15 @@ class SessionPlan:
         two plans in one cell share compilation given equal shapes."""
         return (self.backend, self.runtime, self.scheme, self.semantics,
                 self.kernels)
+
+
+def resolve_hardware(config: SamplerConfig) -> Hardware:
+    """``config.hardware``, or the peak-table row of this process's device
+    kind (an unknown kind raises — see ``perfmodel.hardware_for``)."""
+    if config.hardware is not None:
+        return config.hardware
+    import jax
+    return hardware_for(jax.devices()[0].device_kind)
 
 
 def _mesh_sizes(mesh) -> tuple[int, int]:
@@ -247,7 +258,7 @@ def resolve_plan(config: SamplerConfig, *, n_samples: int, n_sites: int,
     kernels = resolve_kernels(config.kernels)   # raises on unknown modes
 
     p1, p2 = _mesh_sizes(mesh)
-    hw = config.hardware
+    hw = resolve_hardware(config)
     budget = config.device_budget if config.device_budget else hw.mem_capacity
 
     # -- scheme (Eq. 7 TP selector when the mesh has a model axis) ----------

@@ -65,7 +65,8 @@ def config_to_dict(config) -> dict:
         out[f] = _dtype_name(out[f])
     rt = out.get("runtime")
     out["runtime"] = rt if isinstance(rt, (str, type(None))) else rt.name
-    out["hardware"] = dataclasses.asdict(config.hardware)
+    if config.hardware is not None:
+        out["hardware"] = dataclasses.asdict(config.hardware)
     if out.get("chi_profile") is not None:
         out["chi_profile"] = [int(c) for c in out["chi_profile"]]
     if out.get("clamp") is not None:
@@ -84,7 +85,8 @@ def config_from_dict(d: dict):
     d = dict(d)
     for f in _DTYPE_FIELDS:
         d[f] = _dtype_from_name(d.get(f))
-    d["hardware"] = Hardware(**d["hardware"])
+    if d.get("hardware") is not None:
+        d["hardware"] = Hardware(**d["hardware"])
     if d.get("chi_profile") is not None:
         d["chi_profile"] = tuple(int(c) for c in d["chi_profile"])
     return SamplerConfig(**d)
@@ -221,7 +223,9 @@ class RemoteRuntime(ClusterRuntime):
     ``python -m repro.api.remote`` per submit — kept as the measurable
     baseline for ``benchmarks/bench_fleet.py``.
 
-    Either way the subprocess boundary enforces that only the serialized
+    Either way the worker is a child interpreter, which a process holding
+    a TPU refuses to start (``transport.refuse_spawn_on_chip``).  The
+    subprocess boundary enforces that only the serialized
     payload crosses, exactly what an RPC transport to another machine
     would guarantee.  Point :attr:`python` / :attr:`env` at a container or
     remote-exec shim to move the worker off-host; neither the payload
@@ -267,6 +271,8 @@ class RemoteRuntime(ClusterRuntime):
 
     def _submit_oneshot(self, blob: bytes) -> np.ndarray:
         """The PR 5 baseline: a fresh interpreter per batch, serially."""
+        from repro.runtime.transport import refuse_spawn_on_chip
+        refuse_spawn_on_chip("RemoteRuntime")
         env = dict(os.environ if self.env is None else self.env)
         src_root = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))

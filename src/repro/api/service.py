@@ -508,6 +508,7 @@ class SamplingService:
         multi-batch streamed job per-batch checkpoint subdirs with
         automatic mid-chain resume (the ``run_queue`` contract).
         """
+        from repro.api.config import resolve_hardware
         from repro.api.session import SamplingSession
         from repro.core.perfmodel import Workload, job_admission_cost
 
@@ -581,7 +582,7 @@ class SamplingService:
                      chi=session.chi, d=session.d, macro_batch=per_batch,
                      micro_batch=(plan.micro_batch or per_batch),
                      bytes_per_elt=session._elt_bytes)
-        cost = job_admission_cost(w, session.config.hardware,
+        cost = job_admission_cost(w, resolve_hardware(session.config),
                                   n_batches=macro_batches - len(skip))
 
         with self._cond:

@@ -39,7 +39,8 @@ import jax
 import numpy as np
 
 from repro.api.backends import SampleRequest, get_backend
-from repro.api.config import SamplerConfig, SessionPlan, resolve_plan
+from repro.api.config import (SamplerConfig, SessionPlan, resolve_hardware,
+                              resolve_plan)
 from repro.api.runtime import ClusterRuntime, resolve_runtime
 from repro.core.mps import MPS
 from repro.data.gamma_store import GammaStore
@@ -137,7 +138,8 @@ class SamplingSession:
                            scheme=("inmem" if plan.scheme == "seq"
                                    else plan.scheme),
                            micro_batch=plan.micro_batch),
-                w, self.config.hardware, compute_bytes=self._elt_bytes)
+                w, resolve_hardware(self.config),
+                compute_bytes=self._elt_bytes)
             engine_info.pop("scheme", None)      # keep the session-level name
             info.update(engine_info)
             if plan.shard_block:

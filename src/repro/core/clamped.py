@@ -57,7 +57,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import precision
 from repro.core.mps import MPS
 from repro.core.parallel import ParallelConfig, _tp_rescale
@@ -238,7 +237,7 @@ def _clamped_segment_callable(mesh: Mesh, pconfig: ParallelConfig,
         return (samples, env_o.reshape(n_loc, -1), ls_o.reshape(n_loc),
                 lp_o.reshape(n_loc))
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(d_axes), P(d_axes), P(d_axes), P(d_axes), P(), P(),
                   P(), P(None, d_axes), P()),

@@ -143,6 +143,24 @@ def gbs_like_mps(key: Array, n_sites: int, chi: int, d: int,
     return MPS(g, base.lambdas, "linear")
 
 
+def pad_bond(mps: MPS, multiple: int) -> MPS:
+    """Zero-pad the bond dimension up to a multiple of ``multiple``.
+
+    Exact: the padded rows/columns of Γ and entries of Λ are zero, so the
+    padded environment columns stay zero and no probability changes.  The
+    compiled TPU kernels need χ to split into 128-lane blocks (and χ/p₂
+    too under tensor parallelism); χ = 10⁴ does not, so a store is padded
+    once when it is written — never inside a site step, where it would
+    copy Γ on every call.
+    """
+    pad = -mps.chi % multiple
+    if pad == 0:
+        return mps
+    g = jnp.pad(mps.gammas, ((0, 0), (0, pad), (0, pad), (0, 0)))
+    lam = jnp.pad(mps.lambdas, ((0, 0), (0, pad)))
+    return MPS(g, lam, mps.semantics)
+
+
 # ---------------------------------------------------------------------------
 # Exact oracles (for tests and validation — exponential in M, keep M small)
 # ---------------------------------------------------------------------------
@@ -186,6 +204,49 @@ def enumerate_probabilities(mps: MPS) -> np.ndarray:
                 env = env / nrm
         probs[idx] = np.exp(logp)
     return probs / probs.sum()
+
+
+def prefix_marginals(sites, semantics: str = "linear") -> np.ndarray:
+    """Per-site marginals (M, d) of the same joint as
+    :func:`enumerate_probabilities`, for chains too wide for it.
+
+    ``sites`` yields ``(Γ_i, Λ_i)`` one site at a time (e.g. from a
+    ``GammaStore``), so the chain never has to fit in memory at once.
+    All d^i outcome prefixes walk together as one (d^i, χ) environment —
+    one GEMM per site on the default device, at HIGHEST precision and at
+    least float32 — so χ = 10⁴ is cheap while M stays small (memory grows
+    as d^M·χ).
+    """
+    hi = jax.lax.Precision.HIGHEST
+    env = logp = None
+    n_sites = 0
+    for g, lam in sites:
+        n_sites += 1
+        dt = jnp.result_type(g.dtype, jnp.float32)
+        g, lam = jnp.asarray(g, dt), jnp.asarray(lam)
+        chi, d = g.shape[0], g.shape[2]
+        if env is None:
+            env = jnp.zeros((1, chi), dt).at[0, 0].set(1.0)
+            logp = jnp.zeros((1,), jnp.real(env).dtype)
+        temp = jnp.einsum("pl,lrs->prs", env, g, precision=hi)
+        if semantics == "linear":
+            cond = jnp.real(jnp.einsum("prs,r->ps", temp, lam.astype(dt),
+                                       precision=hi))
+        else:
+            cond = jnp.sum(jnp.abs(temp * lam[None, :, None]) ** 2, axis=1)
+            temp = temp * lam[None, :, None]
+        logp = (logp[:, None]
+                + jnp.log(cond / jnp.sum(cond, axis=1, keepdims=True)))
+        logp = logp.reshape(-1)              # prefix-major, outcome-minor
+        env = jnp.moveaxis(temp, 2, 1).reshape(-1, chi)
+        nrm = jnp.sum(jnp.abs(env), axis=1, keepdims=True)
+        env = env / jnp.where(nrm > 0, nrm, 1.0)
+    p = np.exp(np.asarray(logp, dtype=np.float64))
+    p = p / p.sum()
+    per_site = p.reshape((d,) * n_sites)
+    return np.stack([
+        per_site.sum(axis=tuple(a for a in range(n_sites) if a != i))
+        for i in range(n_sites)])
 
 
 def exact_site_marginals(mps: MPS) -> np.ndarray:

@@ -50,7 +50,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import axis_size, shard_map
 from repro.core.mps import MPS
 from repro.core import precision
 from repro.core.sampler import SamplerConfig, draw_from_probs
@@ -142,7 +141,7 @@ def _tp_single_site_step(env, gamma_l, lam, key, config, axis,
         temp_partial = _contract(env, gamma_l, config)    # (N, χ, d) partial
         temp = jax.lax.psum_scatter(temp_partial, axis,
                                     scatter_dimension=1, tiled=True)  # (N, χ/p₂, d)
-        p2 = axis_size(axis)
+        p2 = jax.lax.axis_size(axis)
         idx = jax.lax.axis_index(axis)
         lam_shard = jax.lax.dynamic_slice_in_dim(
             lam, idx * (lam.shape[0] // p2), lam.shape[0] // p2)
@@ -223,7 +222,7 @@ def _tp_double_site_pair(env, gamma_odd_l, lam_odd, gamma_even_r, lam_even,
     env_full, dlog_odd = _tp_rescale(env_full, config.scaling)
 
     # --- even site: Γ split on the right bond; local GEMM, no collective ----
-    p2 = axis_size(axis)
+    p2 = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     lam_shard = jax.lax.dynamic_slice_in_dim(
         lam_even, idx * (lam_even.shape[0] // p2), lam_even.shape[0] // p2)
@@ -316,9 +315,8 @@ def _segment_callable(mesh: Mesh, pconfig: ParallelConfig,
                       config: SamplerConfig):
     """Build the cached shard_map program for one segment of the chain.
 
-    Key data (not typed key arrays) crosses the shard_map boundary — typed
-    PRNG keys do not survive shard_map partitioning on jax 0.4.x (same
-    workaround as ``baseline19_sample``).
+    Key data (not typed key arrays) crosses the shard_map boundary, as in
+    ``baseline19_sample``.
     """
     from repro.core import sampler as S
 
@@ -368,7 +366,7 @@ def _segment_callable(mesh: Mesh, pconfig: ParallelConfig,
 
             return _with_micro(chain, base, env_l, ls_l, L)
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(d_axes), P(d_axes), P(d_axes), P(), P(), P()),
             out_specs=(P(None, d_axes), P(d_axes), P(d_axes)),
@@ -418,7 +416,7 @@ def _segment_callable(mesh: Mesh, pconfig: ParallelConfig,
 
             return _with_micro(chain, base, env_l, ls_l, L)
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(d_axes), P(d_axes, m_axis), P(d_axes),
                       P(None, m_axis, None, None), P(), P()),
@@ -452,7 +450,7 @@ def _segment_callable(mesh: Mesh, pconfig: ParallelConfig,
 
             return _with_micro(chain, base, env_l, ls_l, 2 * n_pairs)
 
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(d_axes), P(d_axes, m_axis), P(d_axes),
                       P(None, m_axis, None, None), P(),
@@ -588,7 +586,7 @@ def _baseline19_sample(mesh: Mesh, mps: MPS, n_samples: int, key: Array,
         rows = jnp.arange(n1) + i
         return emitted[rows][None]          # (1, n1, N1)
 
-    f = shard_map(
+    f = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(pipeline_axis), P(pipeline_axis), P(None, pipeline_axis)),
         out_specs=P(pipeline_axis), check_vma=False,

@@ -12,7 +12,9 @@ import dataclasses
 
 @dataclasses.dataclass(frozen=True)
 class Hardware:
-    """Per-chip capabilities.  Defaults: TPU v5e (the roofline target)."""
+    """Per-chip capabilities.  The defaults repeat the v5e row of
+    :data:`PEAKS` so tests can build variants field by field; a running
+    process plans with :func:`hardware_for` its own device kind."""
     peak_flops: float = 197e12          # bf16 MXU
     hbm_bw: float = 819e9               # bytes/s
     ici_bw: float = 50e9                # bytes/s per link
@@ -32,7 +34,33 @@ class Hardware:
 
 A100 = Hardware(peak_flops=156e12, hbm_bw=2039e9, ici_bw=300e9, io_bw=5e9,
                 mem_capacity=80e9)
-TPU_V5E = Hardware()
+
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at
+# 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect (four links, 50 GB/s
+# each).  io_bw is the paper's NVMe read figure, not the chip's.
+TPU_V5E = Hardware(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9, io_bw=5e9,
+                   mem_capacity=16e9)
+
+#: Per-chip peaks keyed by ``jax.devices()[0].device_kind``.  A kind that
+#: is not here is an error (:func:`hardware_for`), never a default.
+PEAKS: dict[str, Hardware] = {
+    "TPU v5 lite": TPU_V5E,
+    # The CPU backend (tests, interpret-mode kernels) has no published
+    # peaks.  It plans with the v5e figures so a CPU run resolves the same
+    # schedules a v5e would; no CPU time is ever reported against them.
+    "cpu": TPU_V5E,
+}
+
+
+def hardware_for(device_kind: str) -> Hardware:
+    """The :data:`PEAKS` row for a ``device_kind``; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak-table row for device kind {device_kind!r} (have "
+            f"{sorted(PEAKS)}) — add the chip's published peaks to "
+            f"repro.core.perfmodel.PEAKS") from None
 
 
 @dataclasses.dataclass(frozen=True)

@@ -92,12 +92,13 @@ def decode_gamma(raw: np.ndarray, gshape: tuple[int, ...], two_byte: bool,
     construction.  ``raw`` may carry a leading stack axis (a whole segment
     decodes in one call)."""
     lead = raw.shape[:max(0, raw.ndim - len(gshape))]
+    g = raw
     if two_byte:
-        g = jnp.asarray(raw.view(np.uint16)).view(storage_dtype)
-        g = g.reshape(lead + tuple(gshape))
-    else:
-        g = jnp.asarray(raw)
-    return np.asarray(g.astype(compute_dtype))
+        # host-side: ml_dtypes gives numpy the 2-byte float types, so the
+        # decode never round-trips through the accelerator
+        g = raw.view(np.uint16).view(storage_dtype).reshape(
+            lead + tuple(gshape))
+    return g.astype(compute_dtype, copy=False)
 
 
 def segment_checksum(gamma: np.ndarray, lam: np.ndarray) -> int:

@@ -32,6 +32,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.site_step import (COMPILER_PARAMS, I0, acc_dtype_for,
+                                     block_grid, out_dtype_for)
+
 Array = jax.Array
 
 
@@ -44,14 +47,13 @@ def _kernel(env_ref, gamma_ref, samples_ref, out_ref, acc_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     env = env_ref[...]                         # (BN, BL)
-    gam = gamma_ref[...]                       # (BL, BR, d)
-    s_n = samples_ref[...]                     # (BN,) int32
+    s_n = samples_ref[...]                     # (BN, 1) int32
     acc_dtype = acc_ref.dtype
 
     for s in range(d):                         # d ≤ ~6: unrolled, VMEM-local
-        mask = (s_n == s).astype(env.dtype)[:, None]
+        masked = jnp.where(s_n == s, env, jnp.zeros_like(env))
         acc_ref[...] += jax.lax.dot_general(
-            env * mask, gam[:, :, s],
+            masked, gamma_ref[:, s, :],        # (BL, BR), lane-dense
             (((1,), (0,)), ((), ())),
             preferred_element_type=acc_dtype,
         )
@@ -67,17 +69,15 @@ def collapse_select(env: Array, gamma: Array, samples: Array,
                     interpret: bool = False) -> Array:
     """env (N, L), Γ (L, R, d), samples (N,) → env' (N, R).
 
-    L is the (possibly sharded) left bond, R the right bond.  Block sizes
-    MXU-aligned; VMEM working set ≈ BN·BL + BL·BR·d + BN·BR fp32 words.
+    L is the (possibly sharded) left bond, R the right bond.  Γ enters as
+    its lane-dense ``(L, d, R)`` bitcast and the samples as an (N, 1)
+    column; VMEM working set ≈ BN·BL + d·BL·BR + BN·BR fp32 words.
     """
     n, L = env.shape
     _, R, d = gamma.shape
-    bn, br, bl = min(bn, n), min(br, R), min(bl, L)
-    assert n % bn == 0 and R % br == 0 and L % bl == 0, (n, L, R, bn, br, bl)
-    grid = (n // bn, R // br, L // bl)
-    out_dtype = (jnp.float32 if env.dtype in (jnp.bfloat16, jnp.float16)
-                 else env.dtype)
-    acc_dtype = jnp.float64 if env.dtype == jnp.float64 else jnp.float32
+    bn, br, bl, grid = block_grid(n, L, R, bn, br, bl)
+    out_dtype = out_dtype_for(env.dtype)
+    acc_dtype = acc_dtype_for(env.dtype, interpret)
 
     kern = functools.partial(_kernel, n_l=grid[2], d=d, out_dtype=out_dtype)
     return pl.pallas_call(
@@ -85,14 +85,16 @@ def collapse_select(env: Array, gamma: Array, samples: Array,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, bl), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bl, br, d), lambda i, j, k: (k, j, 0)),
-            pl.BlockSpec((bn,), lambda i, j, k: (i,)),
+            pl.BlockSpec((bl, d, br), lambda i, j, k: (k, I0, j)),
+            pl.BlockSpec((bn, 1), lambda i, j, k: (i, I0)),
         ],
         out_specs=pl.BlockSpec((bn, br), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, R), out_dtype),
         scratch_shapes=[pltpu.VMEM((bn, br), acc_dtype)],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(env, gamma, samples.astype(jnp.int32))
+    )(env, jnp.swapaxes(gamma, 1, 2),
+      samples.astype(jnp.int32).reshape(-1, 1))
 
 
 def measure_weights(gamma: Array, lam: Array) -> Array:

@@ -11,9 +11,10 @@ hierarchy (HBM↔VMEM instead of NIC).
 TPU mapping (DESIGN.md §2):
   * grid = (n_tiles, r_tiles, l_tiles), l innermost (sequential reduction on
     TPU, accumulator lives in a VMEM scratch tile).
-  * MXU tiles: BN×BL · BL×(BR·d) with fp32 accumulation
+  * MXU tiles: d GEMMs BN×BL · BL×BR per step (one per outcome, on the
+    lane-dense ``(χl, d, χr)`` view of Γ) with fp32 accumulation
     (``preferred_element_type``); inputs may be bf16 (the paper's TF32 tier).
-  * probs is accumulated across r-tiles into the same (BN, d) output block —
+  * probs is accumulated across r-tiles into the same (d, BN, 1) output block —
     legal because TPU grids execute sequentially and the probs BlockSpec
     ignores the r/l grid axes.
 """
@@ -26,46 +27,46 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.site_step import (COMPILER_PARAMS, I0, acc_dtype_for,
+                                     block_grid, out_dtype_for)
+
 Array = jax.Array
 
 
 def _kernel(env_ref, gamma_ref, lam_ref, temp_ref, probs_ref, acc_ref,
-            *, n_l: int, out_dtype, acc_dtype):
+            *, n_l: int, d: int, out_dtype):
     j = pl.program_id(1)      # r tile
     k = pl.program_id(2)      # l tile (reduction)
+    acc_dtype = acc_ref.dtype
 
     @pl.when(k == 0)
     def _init_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     env = env_ref[...]                              # (BN, BL)
-    gam = gamma_ref[...]                            # (BL, BR, d)
-    bl, br, d = gam.shape
-    acc_ref[...] += jax.lax.dot_general(
-        env, gam.reshape(bl, br * d),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=acc_dtype,
-    ).reshape(env.shape[0], br, d)
+    for s in range(d):
+        acc_ref[s] += jax.lax.dot_general(
+            env, gamma_ref[:, s, :],                # (BL, BR), lane-dense
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=acc_dtype)
 
     @pl.when(k == n_l - 1)
     def _emit():
-        temp = acc_ref[...]
-        temp_ref[...] = temp.astype(out_dtype)
-        # partial measurement over this r tile: (BN, BR, d) · (BR,) → (BN, d)
-        contrib = jax.lax.dot_general(
-            temp.swapaxes(1, 2).reshape(-1, br),        # (BN·d, BR)
-            lam_ref[...].astype(acc_dtype),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=acc_dtype,
-        ).reshape(temp.shape[0], d)
+        lam = lam_ref[...].astype(acc_dtype)        # (1, BR)
+        for s in range(d):
+            temp = acc_ref[s]                       # (BN, BR)
+            temp_ref[:, s, :] = temp.astype(out_dtype)
+            # partial measurement over this r tile: (BN, BR) · (BR,) → (BN, 1)
+            contrib = jnp.sum(temp * lam, axis=1,
+                              keepdims=True).astype(out_dtype)
 
-        @pl.when(j == 0)
-        def _set():
-            probs_ref[...] = contrib.astype(out_dtype)
+            @pl.when(j == 0)
+            def _set():
+                probs_ref[s] = contrib
 
-        @pl.when(j > 0)
-        def _add():
-            probs_ref[...] += contrib.astype(out_dtype)
+            @pl.when(j > 0)
+            def _add():
+                probs_ref[s] += contrib
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "br", "bl", "interpret"))
@@ -74,38 +75,36 @@ def contract_measure(env: Array, gamma: Array, lam: Array,
                      interpret: bool = False):
     """env (N, χ), Γ (χ, χ, d), Λ (χ) → (temp (N, χ, d), probs (N, d)).
 
-    Block sizes default to MXU-aligned 256 (multiples of 128); VMEM working
-    set ≈ BN·BL + BL·BR·d + BN·BR·d fp32 words ≈ 1.3 MB at defaults, d=4.
+    Γ enters as its ``(χl, d, χr)`` bitcast and temp leaves as ``(N, d, χr)``
+    (bitcast back by the wrapper), so every block is lane-dense; the probs
+    accumulate as ``(d, N, 1)`` columns.  VMEM working set ≈ BN·BL +
+    d·BL·BR + 2·d·BN·BR words.
     """
     n, chi = env.shape
     _, chir, d = gamma.shape
-    bn = min(bn, n)
-    br = min(br, chir)
-    bl = min(bl, chi)
-    assert n % bn == 0 and chir % br == 0 and chi % bl == 0, (n, chi, bn, br, bl)
-    grid = (n // bn, chir // br, chi // bl)
-    out_dtype = jnp.float32 if env.dtype in (jnp.bfloat16, jnp.float16) else env.dtype
-    acc_dtype = jnp.float64 if env.dtype == jnp.float64 else jnp.float32
+    bn, br, bl, grid = block_grid(n, chi, chir, bn, br, bl)
+    out_dtype = out_dtype_for(env.dtype)
+    acc_dtype = acc_dtype_for(env.dtype, interpret)
 
-    kern = functools.partial(_kernel, n_l=grid[2], out_dtype=out_dtype,
-                             acc_dtype=acc_dtype)
+    kern = functools.partial(_kernel, n_l=grid[2], d=d, out_dtype=out_dtype)
     temp, probs = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, bl), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bl, br, d), lambda i, j, k: (k, j, 0)),
-            pl.BlockSpec((br,), lambda i, j, k: (j,)),
+            pl.BlockSpec((bl, d, br), lambda i, j, k: (k, I0, j)),
+            pl.BlockSpec((1, br), lambda i, j, k: (I0, j)),
         ],
         out_specs=[
-            pl.BlockSpec((bn, br, d), lambda i, j, k: (i, j, 0)),
-            pl.BlockSpec((bn, d), lambda i, j, k: (i, 0)),
+            pl.BlockSpec((bn, d, br), lambda i, j, k: (i, I0, j)),
+            pl.BlockSpec((d, bn, 1), lambda i, j, k: (I0, i, I0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n, chir, d), out_dtype),
-            jax.ShapeDtypeStruct((n, d), out_dtype),
+            jax.ShapeDtypeStruct((n, d, chir), out_dtype),
+            jax.ShapeDtypeStruct((d, n, 1), out_dtype),
         ],
-        scratch_shapes=[pltpu.VMEM((bn, br, d), acc_dtype)],
+        scratch_shapes=[pltpu.VMEM((d, bn, br), acc_dtype)],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(env, gamma, lam)
-    return temp, probs
+    )(env, jnp.swapaxes(gamma, 1, 2), lam.reshape(1, -1))
+    return jnp.swapaxes(temp, 1, 2), probs[:, :, 0].T

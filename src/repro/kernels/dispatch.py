@@ -30,11 +30,13 @@ real TPU backend and XLA elsewhere (tests force ``"pallas"`` explicitly
 and the kernels run under ``interpret=True``).
 
 The **autotuner** picks Pallas block sizes per shape: on TPU a timed sweep
-over MXU-aligned candidates (cached per process), elsewhere a deterministic
-heuristic table (largest divisors under a VMEM budget) — interpret-mode
-numerics do not depend on the block choice, so CI exercises the same code
-path the TPU runs.  ``autotune_cache_stats()`` reports cache behaviour
-(surfaced by ``launch/sample.py --kernels``).
+over (8, 128)-legal candidates (cached per process), elsewhere a
+deterministic heuristic table (largest legal tiles under a lane-padded VMEM
+model) — interpret-mode numerics do not depend on the block choice, so CI
+exercises the same code path the TPU runs.  ``autotune_cache_stats()`` and
+``autotune_report()`` report cache behaviour, the chosen blocks and the
+candidates the compiler rejected (surfaced by ``launch/sample.py
+--kernels``).
 """
 from __future__ import annotations
 
@@ -112,12 +114,18 @@ class BlockConfig:
     bl: int
 
 
-# heuristic VMEM budget: a v5e core has ~16 MB; leave headroom for the
-# compiler's own double buffering of the streamed operand tiles
-_VMEM_BUDGET_BYTES = 12 * 2 ** 20
+# working-set budget the block choice must fit: below the compiled kernels'
+# scoped VMEM limit (site_step.VMEM_LIMIT_BYTES), leaving room for the
+# compiler's own temporaries of the epilogue's full (BN, χr) rows
+_VMEM_BUDGET_BYTES = 64 * 2 ** 20
+
+# TPU block rule: the last two block dims are multiples of (8, 128) or the
+# whole array dims.  BN is a sublane dim, BR/BL lane dims.
+_SUBLANE, _LANE = 8, 128
 
 _cache: dict[tuple, BlockConfig] = {}
-_stats = {"hits": 0, "misses": 0, "swept": 0}
+_stats = {"hits": 0, "misses": 0, "swept": 0, "rejected": 0}
+_report: dict[tuple, dict] = {}
 
 
 def autotune_cache_stats() -> dict:
@@ -125,77 +133,116 @@ def autotune_cache_stats() -> dict:
     return {"entries": len(_cache), **_stats}
 
 
+def autotune_report() -> list[dict]:
+    """One record per tuned cell: the chosen blocks, how many candidates
+    were timed and how many the compiler rejected (with the first error)."""
+    return [dict(r) for r in _report.values()]
+
+
 def clear_autotune_cache() -> None:
     _cache.clear()
-    _stats.update(hits=0, misses=0, swept=0)
+    _report.clear()
+    _stats.update(hits=0, misses=0, swept=0, rejected=0)
 
 
-def _divisor_tile(size: int, pref: int) -> int:
-    """Largest divisor of ``size`` that is ≤ ``pref`` — non-power-of-two and
-    prime dimensions degrade gracefully (worst case: the whole dimension,
-    which is always a legal Pallas block)."""
-    for t in range(min(pref, size), 0, -1):
+def _legal_tile(size: int, pref: int, align: int) -> int:
+    """Largest divisor of ``size`` that is ≤ ``pref`` and a multiple of
+    ``align``; the whole dimension when there is none (always a legal
+    block — and the only one for a χ like 10⁴ that no multiple of 128
+    divides, which the VMEM model then rejects)."""
+    for t in range(min(pref, size) // align * align, 0, -align):
         if size % t == 0:
             return t
     return size
 
 
+def _padded_bytes(shape: tuple, elt: int) -> int:
+    """VMEM bytes of one buffer under the (sublane, 128) tiling: the minor
+    dim pads to 128 lanes and the next to a whole sublane tile (8 rows of
+    4 bytes, 16 of 2) — a (BN, 1) column costs BN·128 words, not BN."""
+    *lead, rows, lanes = (1,) + tuple(shape) if len(shape) == 1 else shape
+    sub = max(1, 32 // elt)
+    out = (-(-rows // sub) * sub) * (-(-lanes // _LANE) * _LANE) * elt
+    for x in lead:
+        out *= x
+    return out
+
+
 def _working_set_bytes(stage: str, cfg: BlockConfig, chi_r: int, d: int,
                        elt: int, planes: int) -> int:
-    """VMEM model of a block choice (the site_step slab dominates)."""
+    """VMEM model of a block choice: pipelined operand/result blocks count
+    twice (double buffering), scratch once, all lane/sublane-padded."""
     bn, br, bl = cfg.bn, cfg.br, cfg.bl
+
+    def b(*shape):
+        return _padded_bytes(shape, elt)
+
+    env_in, gam_in = 2 * b(bn, bl), 2 * bl * b(d, br)
     if stage == "site_step":
-        # env tile + Γ tile + split-K acc + resident temp slab + env' row
-        per_plane = bn * bl + bl * br * d + bn * br * d + bn * chi_r * d
-        return (planes * per_plane + bn * chi_r + bn * d) * elt
+        # per plane: env + Γ blocks, split-K acc, the resident temp slab,
+        # the env' row block (×2) and two epilogue full-row temporaries
+        per_plane = (env_in + gam_in + d * b(bn, br) + d * b(bn, chi_r)
+                     + 4 * b(bn, chi_r))
+        vectors = 2 * b(1, br) + 6 * b(bn, 1) + d * b(bn, 1)
+        return planes * per_plane + vectors
     if stage == "contract_measure":
-        return (bn * bl + bl * br * d + 2 * bn * br * d + bn * d) * elt
+        return (env_in + gam_in + 2 * b(1, br) + 2 * bn * b(d, br)
+                + 2 * d * b(bn, 1) + d * b(bn, br))
     if stage == "collapse":
-        return (bn * bl + bl * br * d + 2 * bn * br) * elt
+        return env_in + gam_in + 2 * b(bn, 1) + 3 * b(bn, br)
     if stage == "measure":
-        return (bn * bl + bl * d + 2 * bn * d) * elt
+        return env_in + 2 * b(bl, d) + 3 * b(bn, d)
     raise ValueError(stage)
 
 
 def _heuristic(stage: str, n: int, chi_l: int, chi_r: int, d: int,
                elt: int, planes: int) -> BlockConfig:
-    """Deterministic block choice: MXU-preferred divisors, then shrink BN
-    (the only axis the site_step slab scales with) until the VMEM model
-    fits.  Correctness never depends on the choice — any divisors work."""
-    cfg = BlockConfig(bn=_divisor_tile(n, 256), br=_divisor_tile(chi_r, 256),
-                      bl=_divisor_tile(chi_l, 256))
+    """Deterministic block choice: the largest legal tiles under the MXU
+    preferences, then shrink BN (the only axis the site_step slab scales
+    with), BR and BL until the VMEM model fits.  Correctness never depends
+    on the choice — any divisors work in interpret mode — but a choice
+    that cannot fit raises here rather than in the TPU compiler."""
+    cfg = BlockConfig(bn=_legal_tile(n, 256, _SUBLANE),
+                      br=_legal_tile(chi_r, 512, _LANE),
+                      bl=_legal_tile(chi_l, 512, _LANE))
     while (_working_set_bytes(stage, cfg, chi_r, d, elt, planes)
            > _VMEM_BUDGET_BYTES):
-        if cfg.bn > 1:                       # the slab scales with BN first
-            cfg = dataclasses.replace(cfg, bn=_divisor_tile(n, cfg.bn // 2))
-        elif cfg.br > 1:
-            cfg = dataclasses.replace(cfg, br=_divisor_tile(chi_r,
-                                                            cfg.br // 2))
-        elif cfg.bl > 1:
-            cfg = dataclasses.replace(cfg, bl=_divisor_tile(chi_l,
-                                                            cfg.bl // 2))
-        else:                                # χ itself exceeds the model —
-            break                            # compile anyway, VMEM will tell
+        for field, size, align in (("bn", n, _SUBLANE), ("br", chi_r, _LANE),
+                                   ("bl", chi_l, _LANE)):
+            cur = getattr(cfg, field)
+            smaller = _legal_tile(size, cur // 2, align)
+            if smaller < cur:
+                cfg = dataclasses.replace(cfg, **{field: smaller})
+                break
+        else:
+            raise ValueError(
+                f"no block choice for stage {stage!r} at N={n}, "
+                f"χ=({chi_l}, {chi_r}), d={d} fits the "
+                f"{_VMEM_BUDGET_BYTES >> 20} MiB VMEM budget under the "
+                f"(8, 128) tiling rule — pad the bond to a multiple of 128 "
+                f"once at store time (repro.core.mps.pad_bond)")
     return cfg
 
 
 def _sweep_candidates(stage: str, n: int, chi_l: int, chi_r: int, d: int,
                       elt: int, planes: int) -> list[BlockConfig]:
-    """MXU-aligned candidate grid for the timed TPU sweep (budget-filtered)."""
-    seen, out = set(), []
-    for pn in (512, 256, 128, 64):
-        for pr in (512, 256, 128):
-            for plb in (512, 256, 128):
-                cfg = BlockConfig(bn=_divisor_tile(n, pn),
-                                  br=_divisor_tile(chi_r, pr),
-                                  bl=_divisor_tile(chi_l, plb))
+    """Legal, budget-filtered candidate grid for the timed TPU sweep; the
+    heuristic's own choice always leads."""
+    first = _heuristic(stage, n, chi_l, chi_r, d, elt, planes)
+    seen, out = {first}, [first]
+    for pn in (256, 128, 64):
+        for pr in (512, 256):
+            for plb in (1024, 512):
+                cfg = BlockConfig(bn=_legal_tile(n, pn, _SUBLANE),
+                                  br=_legal_tile(chi_r, pr, _LANE),
+                                  bl=_legal_tile(chi_l, plb, _LANE))
                 if cfg in seen:
                     continue
                 seen.add(cfg)
                 if (_working_set_bytes(stage, cfg, chi_r, d, elt, planes)
                         <= _VMEM_BUDGET_BYTES):
                     out.append(cfg)
-    return out or [_heuristic(stage, n, chi_l, chi_r, d, elt, planes)]
+    return out
 
 
 def _time_call(fn: Callable, *args, iters: int = 3) -> float:
@@ -220,7 +267,8 @@ def autotune(stage: str, *, n: int, chi_l: int, chi_r: int, d: int,
     answers immediately.  On TPU, ``probe(cfg)`` must return a zero-arg
     thunk running the kernel at ``cfg``; the fastest candidate wins and is
     cached, so a production sampler pays the sweep once per distinct
-    (χ-bucket, N₂) shape.
+    (χ-bucket, N₂) shape.  Candidates the compiler rejects are counted
+    (``autotune_report``); when every one is rejected this raises.
     """
     elt = jax.numpy.dtype(dtype).itemsize
     key = (stage, n, chi_l, chi_r, d, str(jax.numpy.dtype(dtype)), planes,
@@ -230,20 +278,35 @@ def autotune(stage: str, *, n: int, chi_l: int, chi_r: int, d: int,
         _stats["hits"] += 1
         return hit
     _stats["misses"] += 1
+    rec = {"stage": stage, "n": n, "chi_l": chi_l, "chi_r": chi_r, "d": d,
+           "dtype": key[5], "candidates": 1, "rejected": 0, "error": None}
     if probe is not None and on_tpu():
         best_cfg, best_t = None, float("inf")
-        for cfg in _sweep_candidates(stage, n, chi_l, chi_r, d, elt, planes):
+        cands = _sweep_candidates(stage, n, chi_l, chi_r, d, elt, planes)
+        rec["candidates"] = len(cands)
+        for cfg in cands:
             _stats["swept"] += 1
             try:
                 t = _time_call(probe(cfg))
-            except Exception:       # a candidate the compiler rejects
+            except Exception as e:  # the compiler refused this candidate
+                _stats["rejected"] += 1
+                rec["rejected"] += 1
+                rec["error"] = rec["error"] or f"{cfg}: {e}"[:500]
                 continue
             if t < best_t:
                 best_cfg, best_t = cfg, t
-        cfg = best_cfg or _heuristic(stage, n, chi_l, chi_r, d, elt, planes)
+        if best_cfg is None:
+            raise RuntimeError(
+                f"the TPU compiler rejected all {len(cands)} block "
+                f"candidates for {stage} at N={n}, χ=({chi_l}, {chi_r}), "
+                f"d={d}; first error: {rec['error']}")
+        cfg = best_cfg
+        rec["best_s"] = best_t
     else:
         cfg = _heuristic(stage, n, chi_l, chi_r, d, elt, planes)
+    rec["blocks"] = dataclasses.asdict(cfg)
     _cache[key] = cfg
+    _report[key] = rec
     return cfg
 
 

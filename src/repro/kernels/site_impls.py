@@ -30,17 +30,11 @@ Array = jax.Array
 
 
 def draw_from_uniform(probs: Array, u: Array) -> Array:
-    """Alg. 1 lines 2-4 given the per-sample uniforms: normalise, cumsum,
-    threshold draw.  probs (N, d) ≥ 0; u (N, 1) in [0, 1)."""
-    probs = jnp.clip(probs, 0.0, None)
-    total = jnp.sum(probs, axis=1, keepdims=True)
-    # Guard fully-underflowed rows: fall back to uniform (paper Fig. 6 failure
-    # mode — with per-sample scaling this should never trigger).
-    safe = jnp.where(total > 0, probs / jnp.where(total > 0, total, 1.0),
-                     jnp.ones_like(probs) / probs.shape[1])
-    cdf = jnp.cumsum(safe, axis=1)
-    return jnp.sum((u > cdf).astype(jnp.int32), axis=1).clip(
-        0, probs.shape[1] - 1)
+    """Alg. 1 lines 2-4 given the per-sample uniforms: normalise, running
+    sum, threshold draw — the same :func:`site_step.draw_columns` the fused
+    kernels run.  probs (N, d) ≥ 0; u (N, 1) in [0, 1) → samples (N,)."""
+    cols = [probs[:, s:s + 1] for s in range(probs.shape[1])]
+    return SS.draw_columns(cols, u)[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -122,7 +116,7 @@ def site_step_linear_pallas(env, gamma, lam, u, *, scaling, compute_dtype):
         interpret=not on_tpu())
     if scaling == "global":            # the global max crosses n-tiles
         env2, dlog = precision.rescale(env2, "global")
-    return env2, samples.astype(jnp.int_), dlog
+    return env2, samples, dlog
 
 
 @register_site_op("site_step", "born", "pallas")
@@ -135,7 +129,7 @@ def site_step_born_pallas(env, gamma, lam, u, *, scaling, compute_dtype):
         scaling=fused_scaling, interpret=not on_tpu())
     if scaling == "global":
         env2, dlog = precision.rescale(env2, "global")
-    return env2, samples.astype(jnp.int_), dlog
+    return env2, samples, dlog
 
 
 # ---------------------------------------------------------------------------
@@ -248,10 +242,13 @@ def warm_site_step(n: int, chi: int, d: int, dtype, *, semantics: str,
     the session backends call this *before* jitting the chain walk.
     """
     planes = 2 if semantics == "born" else 1
-    rdt = jnp.zeros((), dtype=dtype).real.dtype
+    # the walk's env never carries a half-precision Γ storage dtype, and
+    # the in-trace lookups key on the env — warm under that key
+    env_dt = _env_dtype_of(dtype)
+    rdt = jnp.zeros((), dtype=env_dt).real.dtype
     probe = None
     if on_tpu():
-        env = jnp.zeros((n, chi), dtype=dtype)
+        env = jnp.zeros((n, chi), dtype=env_dt)
         gamma = jnp.zeros((chi, chi, d), dtype=dtype)
         lam = jnp.zeros((chi,), dtype=rdt)
         u = jnp.zeros((n,), dtype=rdt)
@@ -265,7 +262,7 @@ def warm_site_step(n: int, chi: int, d: int, dtype, *, semantics: str,
             return lambda: kern(env, gamma, lam, u, bn=cfg.bn, br=cfg.br,
                                 bl=cfg.bl, scaling=fused_scaling, **kw)
 
-    autotune("site_step", n=n, chi_l=chi, chi_r=chi, d=d, dtype=dtype,
+    autotune("site_step", n=n, chi_l=chi, chi_r=chi, d=d, dtype=env_dt,
              planes=planes, probe=probe)
 
 

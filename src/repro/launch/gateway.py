@@ -75,6 +75,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     from repro import api
+    from repro.launch.compile_cache import enable_compile_cache
+    print("compile cache:", enable_compile_cache())
     from repro.obs import (MetricsRegistry, instrument_dispatch,
                            instrument_service)
     from repro.serve import Gateway, ResultCache, TenantTable

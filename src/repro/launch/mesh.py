@@ -7,16 +7,30 @@ all-reduce crosses it once per step; the sampler shards samples over it).
 
 Functions, not module constants: importing this module never touches jax
 device state (the dry-run must set XLA_FLAGS before first jax init).
+
+Every mesh here uses ``Auto`` axes.  ``jax.make_mesh`` defaults to
+``Explicit`` axes, under which sharding is part of an array's type and a
+plain slice of a sharded operand (``env[:, :χ]`` in
+``core.dynamic_bond.fit_env``) raises ``ShardingTypeError``; the sampler's
+collectives are placed by ``shard_map``, so it wants the compiler-placed
+``Auto`` semantics.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with ``Auto`` axis types (see module docstring)."""
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_host_mesh(model: int = 1):
@@ -28,7 +42,7 @@ def make_host_mesh(model: int = 1):
     device view rather than assuming the local host."""
     n = len(jax.devices())
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def data_axis_names(mesh) -> tuple[str, ...]:

@@ -45,6 +45,7 @@ from repro.core import dynamic_bond as DB
 from repro.core import mps as M
 from repro.data.gamma_store import MANIFEST_NAME, GammaStore
 from repro.kernels import dispatch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime.elastic import WorkQueue
 from repro.runtime.faults import DeadLetter, FaultError
 
@@ -112,6 +113,7 @@ def _main() -> None:
     ap.add_argument("--segment-len", type=int, default=0,
                     help="sites per streamed segment (0 = perfmodel planner)")
     args = ap.parse_args()
+    print("compile cache:", enable_compile_cache())
 
     os.makedirs(args.out, exist_ok=True)
     # the runtime decides where devices live; the mesh is derived from it

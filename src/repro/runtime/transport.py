@@ -126,6 +126,27 @@ def array_from_frame(body: bytes) -> np.ndarray:
 # the client side: one persistent worker
 # ---------------------------------------------------------------------------
 
+def refuse_spawn_on_chip(what: str) -> None:
+    """One process per chip: raise instead of starting a child interpreter
+    when this process already holds a TPU.  A chip belongs to one process
+    at a time, so a child that needs it would fail on libtpu's lock or
+    hang waiting for it.  A parent that never initialized JAX holds
+    nothing and may spawn freely."""
+    if "jax" not in sys.modules:
+        return
+    import jax
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{what}: this process holds the TPU, and a chip belongs to one "
+            f"process at a time — a child interpreter would fail or hang "
+            f"waiting for it.  Sample in this process (runtime='local', no "
+            f"service fleet), or start the workers from a parent that "
+            f"never initializes JAX.")
+
+
 def _src_root() -> str:
     return os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
@@ -143,6 +164,7 @@ class WorkerProcess:
 
     def __init__(self, name: str, python: Optional[str] = None,
                  env: Optional[dict] = None, timeout: float = 600.0):
+        refuse_spawn_on_chip(f"worker {name!r}")
         self.name = name
         self.timeout = timeout
         self.batches = 0                  # results streamed back
@@ -359,7 +381,8 @@ class LaneHealth:
 
 
 class WorkerPool:
-    """Named persistent workers, spawned/reaped on demand.
+    """Named persistent workers, spawned/reaped on demand (never from a
+    process that holds a TPU — see :func:`refuse_spawn_on_chip`).
 
     The service's fleet lanes map 1:1 onto pool workers: ``add_worker`` →
     :meth:`spawn`, ``remove_worker`` → :meth:`reap`, one ``call`` per

@@ -161,7 +161,8 @@ def _sample(mps, n: int, cfg: ScenarioConfig, clamp=None):
     config = api.SamplerConfig(scheme=cfg.scheme, backend=cfg.backend,
                                clamp=clamp)
     key = jax.random.key(cfg.seed + 1)
-    mesh = (jax.make_mesh((jax.device_count(),), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = (make_mesh((jax.device_count(),), ("data",))
             if cfg.scheme == "dp" else None)
     if cfg.backend == "streamed":
         import jax.numpy as jnp

@@ -642,3 +642,21 @@ def test_runtime_matrix_multihost_dp_tp(runtime_matrix_results, cell):
         else cell[: -len("_one_reader")]
     assert runtime_matrix_results[scheme + "_errs"] == []
     assert runtime_matrix_results[cell]
+
+
+def test_compile_cache_location(monkeypatch, tmp_path):
+    """The entry points' compile cache: $JAX_COMPILATION_CACHE_DIR when set
+    (and nothing else is set), else a fixed <checkout>/.jax_cache."""
+    from repro.launch import compile_cache as CC
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert CC.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = CC.enable_compile_cache()
+        assert path == os.path.join(CC.CHECKOUT, ".jax_cache")
+        assert os.path.isfile(os.path.join(CC.CHECKOUT, "ROADMAP.md"))
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

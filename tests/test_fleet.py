@@ -418,3 +418,20 @@ def test_remote_runtime_persistent_worker_reuse(chain):
     with api.SamplingSession(chain, cfg2) as s:
         assert np.array_equal(np.asarray(s.sample(16, key)), a)
     assert not np.array_equal(a, b)
+
+
+def test_no_child_spawned_from_a_tpu_parent(monkeypatch):
+    """One process per chip: a parent holding a TPU refuses to start a
+    worker interpreter (it would fail or hang on the chip) — for the
+    service pool and both RemoteRuntime modes — and spawns nothing."""
+    from repro.api.remote import RemoteRuntime
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pool = transport.WorkerPool()
+    with pytest.raises(RuntimeError, match="one process"):
+        pool.spawn("lane-0")
+    assert pool.spawned == 0 and not pool.workers
+    for persistent in (True, False):
+        rt = RemoteRuntime(persistent=persistent)
+        with pytest.raises(RuntimeError, match="one process"):
+            rt.submit({"v": 2})
+        assert rt._worker is None

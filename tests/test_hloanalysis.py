@@ -57,9 +57,8 @@ def test_collective_wire_bytes():
         mesh = jax.make_mesh((4,), ("x",))
         def f(v):
             return jax.lax.psum(v, "x")
-        from repro.compat import shard_map
-        g = shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
-                      check_vma=False)
+        g = jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
+                          check_vma=False)
         c = jax.jit(g).lower(jax.ShapeDtypeStruct((1024,), jnp.float32)).compile()
         cost = H.analyze(c.as_text())
         # ring all-reduce of 4 KiB over 4 ranks: 2*4096*(3/4) = 6144 B

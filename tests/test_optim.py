@@ -81,8 +81,8 @@ def test_compressed_psum_single_axis():
     def f(v):
         return C.compressed_psum(v, "pod")
 
-    from repro.compat import shard_map
-    out = shard_map(f, mesh=mesh, in_specs=jax.sharding.PartitionSpec(),
-                    out_specs=jax.sharding.PartitionSpec(),
-                    check_vma=False)(x)
+    out = jax.shard_map(f, mesh=mesh,
+                        in_specs=jax.sharding.PartitionSpec(),
+                        out_specs=jax.sharding.PartitionSpec(),
+                        check_vma=False)(x)
     assert float(jnp.max(jnp.abs(out - x))) < 0.05 * float(jnp.max(jnp.abs(x)))

@@ -161,3 +161,13 @@ def test_seed_consistency_across_schedules(child_results, schedule):
     the streaming engine under each scheme, and both restart paths emit
     bit-identical samples from one seed."""
     assert child_results["consistency"][schedule]
+
+
+def test_meshes_use_auto_axes():
+    """jax.make_mesh defaults to Explicit axes, under which slicing a
+    sharded env (dynamic_bond.fit_env) raises; the repo's meshes are Auto."""
+    from jax.sharding import AxisType
+
+    from repro.launch.mesh import make_host_mesh, make_mesh
+    for mesh in (make_host_mesh(), make_mesh((1,), ("data",))):
+        assert all(t == AxisType.Auto for t in mesh.axis_types)

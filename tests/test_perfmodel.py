@@ -75,3 +75,25 @@ def test_t_site_compute_linear_in_n():
 
 def test_macro_batch_count():
     assert W.n_macro == 500
+
+
+def test_peaks_keyed_by_device_kind():
+    """The planner's peaks come from one table keyed by device_kind; a
+    kind that is not in it raises instead of defaulting to v5e."""
+    assert PM.hardware_for("TPU v5 lite") is PM.TPU_V5E
+    assert PM.TPU_V5E.peak_flops == 197e12 and PM.TPU_V5E.hbm_bw == 819e9
+    assert PM.hardware_for("cpu").mem_capacity > 0
+    with pytest.raises(ValueError, match="no peak-table row"):
+        PM.hardware_for("TPU v99")
+
+
+def test_plan_resolves_hardware_from_device(monkeypatch):
+    from repro.api import config as C
+    cfg = C.SamplerConfig()
+    assert cfg.hardware is None
+    assert C.resolve_hardware(cfg) is PM.hardware_for("cpu")
+    pinned = PM.Hardware(mem_capacity=1e9)
+    assert C.resolve_hardware(C.SamplerConfig(hardware=pinned)) is pinned
+    monkeypatch.delitem(PM.PEAKS, "cpu")
+    with pytest.raises(ValueError, match="cpu"):
+        C.resolve_hardware(cfg)

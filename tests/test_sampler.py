@@ -101,3 +101,37 @@ def test_site_stats_shape(linear_mps_small):
     res = S.sample_chain(mps, state)
     assert res.site_stats.shape == (6, 3)
     assert bool(jnp.all(jnp.isfinite(res.site_stats)))
+
+
+def test_draw_running_sum_matches_cumsum_reference():
+    """The running-sum draw (shared by the fused kernels and the XLA path)
+    picks the same outcome as normalise → cumsum → threshold."""
+    from repro.kernels.site_impls import draw_from_uniform
+    k1, k2 = jax.random.split(jax.random.key(5))
+    probs = jax.random.uniform(k1, (512, 4), dtype=jnp.float64)
+    probs = probs.at[:8].set(0.0)                 # underflowed rows
+    u = jax.random.uniform(k2, (512, 1), dtype=jnp.float64)
+    total = probs.sum(axis=1, keepdims=True)
+    safe = jnp.where(total > 0, probs / jnp.where(total > 0, total, 1.0),
+                     0.25)
+    want = jnp.sum(u > jnp.cumsum(safe, axis=1), axis=1).clip(0, 3)
+    got = draw_from_uniform(probs, u)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("semantics", ["linear", "born"])
+def test_prefix_marginals_match_enumeration(semantics):
+    """The batched-prefix oracle (what the chip smoke run checks against at
+    χ = 10⁴) equals the enumeration oracle, and bond padding is exact."""
+    if semantics == "linear":
+        m = M.gbs_like_mps(jax.random.key(3), 4, 6, 3)
+    else:
+        m = M.random_born_mps(jax.random.key(4), 3, 5, 2)
+    exact = M.exact_site_marginals(m)
+    got = M.prefix_marginals(zip(m.gammas, m.lambdas), semantics)
+    np.testing.assert_allclose(got, exact, atol=1e-12)
+    padded = M.pad_bond(m, 8)
+    assert padded.chi == 8
+    np.testing.assert_allclose(M.exact_site_marginals(padded), exact,
+                               atol=1e-12)

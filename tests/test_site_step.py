@@ -11,6 +11,7 @@ Three layers of coverage:
   ≡ ``kernels="xla"`` bit-for-bit across seq/dp/tp_single/tp_double ×
   static/dynamic-χ (multi-device cells in a forced-8-device subprocess).
 """
+import dataclasses
 import json
 import os
 import subprocess
@@ -310,3 +311,65 @@ def kernel_matrix_results():
 def test_kernel_bitidentity_matrix(kernel_matrix_results, cell):
     """kernels="pallas" ≡ kernels="xla" per seed, every schedule cell."""
     assert kernel_matrix_results[cell], (cell, kernel_matrix_results)
+
+
+# ---------------------------------------------------------------------------
+# TPU rules the interpret mode cannot see: legal tiles, VMEM padding,
+# compiler rejections, f64
+# ---------------------------------------------------------------------------
+
+def test_legal_tiles_and_lane_padded_vmem_model():
+    """Blocks are (8, 128)-aligned divisors or whole dims; a (BN, 1)
+    column costs BN·128 words; χ = 10⁴ has no legal tiling that fits, and
+    the heuristic says so (pad the bond) instead of the TPU compiler."""
+    assert dispatch._legal_tile(10240, 512, 128) == 512
+    assert dispatch._legal_tile(2560, 1024, 128) == 640
+    assert dispatch._legal_tile(10000, 512, 128) == 10000
+    assert dispatch._legal_tile(96, 256, 8) == 96
+    assert dispatch._padded_bytes((64, 1), 4) == 64 * 128 * 4
+    assert dispatch._padded_bytes((4, 512), 2) == 16 * 512 * 2
+    cfg = dispatch._heuristic("site_step", 4096, 10240, 10240, 4, 4, 1)
+    assert cfg.br % 128 == 0 and cfg.bl % 128 == 0 and cfg.bn % 8 == 0
+    assert (dispatch._working_set_bytes("site_step", cfg, 10240, 4, 4, 1)
+            <= dispatch._VMEM_BUDGET_BYTES)
+    with pytest.raises(ValueError, match="pad_bond"):
+        dispatch._heuristic("site_step", 4096, 10000, 10000, 4, 4, 1)
+
+
+def test_autotuner_counts_rejections_and_raises_when_all_fail(monkeypatch):
+    dispatch.clear_autotune_cache()
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    seen = []
+
+    def probe(cfg):
+        seen.append(cfg)
+        if len(seen) == 1:
+            raise RuntimeError("Mosaic: block too large")
+        return lambda: jnp.zeros(())
+
+    cfg = dispatch.autotune("site_step", n=512, chi_l=1024, chi_r=1024, d=4,
+                            dtype=jnp.float32, probe=probe)
+    (rec,) = dispatch.autotune_report()
+    assert rec["rejected"] == 1 and rec["candidates"] == len(seen)
+    assert "block too large" in rec["error"]
+    assert rec["blocks"] == dataclasses.asdict(cfg) and cfg != seen[0]
+
+    refused = []
+
+    def refuse(cfg):
+        refused.append(cfg)
+        raise RuntimeError("Mosaic: no")
+    with pytest.raises(RuntimeError, match="rejected all"):
+        dispatch.autotune("collapse", n=512, chi_l=1024, chi_r=1024, d=4,
+                          dtype=jnp.float32, probe=refuse)
+    assert refused
+    assert dispatch.autotune_cache_stats()["rejected"] == 1 + len(refused)
+    dispatch.clear_autotune_cache()
+
+
+def test_compiled_kernel_refuses_f64_clearly():
+    env, gamma, lam, u = _operands(8, 16, 2, jnp.float64)
+    with pytest.raises(TypeError, match="float64"):
+        site_step_linear(env, gamma, lam, u, interpret=False)
+    with pytest.raises(TypeError, match="float64"):
+        measure_probs(env, gamma[:, 0, :], interpret=False)
