@@ -53,7 +53,7 @@ def main() -> None:
         out = session.sample(n, key)
         st = session.stats
         print(f"streamed {out.shape} samples over {st['segments']} segments; "
-              f"{st['io_hidden_frac']:.0%} of disk time hidden behind "
+              f"{st['io_hidden_frac']:.0%} of fetch time hidden behind "
               f"compute; max {st['max_live_segments']} segments live")
 
     # 4. bit-identical to the all-in-memory scan over the same Γ (the

@@ -78,6 +78,7 @@ from typing import Any, Iterable, Iterator, Optional, Union
 
 import numpy as np
 
+from repro.obs import trace
 from repro.runtime.elastic import WorkQueue
 from repro.runtime.faults import (KINDS, CrashLoopLane, DeadLetter, Fault,
                                   FaultReport, classify, dead_letter_kind)
@@ -237,7 +238,10 @@ class JobHandle:
         for b in job.expected:
             deadline = (None if timeout is None
                         else _time.monotonic() + timeout)
-            with svc._cond:
+            # from the generator's resume to the yield (closed before it:
+            # a span must not stay open across a yield)
+            with trace.span("service.stream_wait", job=job.job_id,
+                            batch=b), svc._cond:
                 while b not in job.blocks:
                     if job.state == FAILED:
                         raise job.error
@@ -772,6 +776,14 @@ class SamplingService:
                      "transport_worker_batches": w.batches if w else None}
 
     def _run_batch(self, job: _Job, b: int, worker: str) -> None:
+        """One claimed batch, from claim to result stored, under a
+        ``service.batch`` span whose attributes every span of the batch
+        inherits."""
+        with trace.span("service.batch", job=job.job_id, batch=b,
+                        lane=worker):
+            self._run_claimed(job, b, worker)
+
+    def _run_claimed(self, job: _Job, b: int, worker: str) -> None:
         from repro.runtime.transport import TransportError
 
         hook = self.batch_hook

@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import trace
 from repro.runtime.faults import CorruptSegment, Fault
 
 #: per-store digest manifest (``write_digest_manifest``): maps each site
@@ -151,7 +152,9 @@ class GammaStore:
         self._prefetched: dict[int, np.ndarray] = {}
         self._inflight: set[int] = set()
         self._lock = threading.Lock()
-        self._queue: "queue.Queue[Optional[int]]" = queue.Queue()
+        # (site, the span that scheduled its read) — the worker's read spans
+        # take that span as their cause
+        self._queue: "queue.Queue[Optional[tuple]]" = queue.Queue()
         self._results: "queue.Queue[tuple[int, np.ndarray]]" = queue.Queue()
         self._thread = threading.Thread(target=self._worker, daemon=True)
         self._thread.start()
@@ -290,8 +293,8 @@ class GammaStore:
         self._digest = None
         return qpath
 
-    def _read_raw(self, i: int) -> tuple[np.ndarray, np.ndarray,
-                                         tuple[int, ...], bool]:
+    def _read_raw(self, i: int, cause: Optional[trace.Span] = None
+                  ) -> tuple[np.ndarray, np.ndarray, tuple[int, ...], bool]:
         """One site's storage-format payload: (packed Γ, Λ, gshape, two_byte).
         This is the only place Γ payload bytes leave the disk — the I/O
         counters here are what the only-root-reads contract asserts on.
@@ -302,7 +305,11 @@ class GammaStore:
         structurally on every read regardless.  Bad bytes get one bounded
         re-read (a transient torn read heals; real rot fails twice), then
         the file is quarantined and :class:`CorruptSegment` raised — no
-        caller ever sees garbage tensors."""
+        caller ever sees garbage tensors.
+
+        Spans ``store.read`` (the file read) and ``store.parse``
+        (``np.load``) cover each attempt; ``cause`` is their parent when
+        the read runs on the prefetch worker."""
         t0 = time.perf_counter()
         path = self._path(i)
         fname = site_filename(i)
@@ -311,7 +318,8 @@ class GammaStore:
         raw = lam = gshape = two_byte = None
         for _attempt in range(2):
             fault = None
-            with open(path, "rb") as fh:   # FileNotFoundError propagates
+            with trace.span("store.read", parent=cause, site=i), \
+                    open(path, "rb") as fh:  # FileNotFoundError propagates
                 data = fh.read()
             if self.verify:
                 expected = self.manifest_leaves().get(fname)
@@ -324,7 +332,8 @@ class GammaStore:
                                     f"against {MANIFEST_NAME} in {self.root}")
                         continue
             try:
-                with np.load(io.BytesIO(data)) as z:
+                with trace.span("store.parse", parent=cause, site=i), \
+                        np.load(io.BytesIO(data)) as z:
                     raw, lam = z["gamma"], z["lam"]
                     gshape = tuple(int(x) for x in z["gshape"])
                     two_byte = bool(z["two_byte"])
@@ -436,18 +445,20 @@ class GammaStore:
         with self._lock:
             self.repaired_sites += 1
 
-    def _read(self, i: int):
-        raw, lam, gshape, two_byte = self._read_raw(i)
-        return decode_gamma(raw, gshape, two_byte, self.storage_dtype,
-                            self.compute_dtype), lam
+    def _read(self, i: int, cause: Optional[trace.Span] = None):
+        raw, lam, gshape, two_byte = self._read_raw(i, cause)
+        with trace.span("store.decode", parent=cause, site=i):
+            return decode_gamma(raw, gshape, two_byte, self.storage_dtype,
+                                self.compute_dtype), lam
 
     def _worker(self):
         while True:
-            i = self._queue.get()
-            if i is None:
+            item = self._queue.get()
+            if item is None:
                 return
+            i, cause = item
             try:
-                self._results.put((i, self._read(i)))
+                self._results.put((i, self._read(i, cause)))
             except Exception as e:          # surfaced on the consumer side
                 self._results.put((i, e))
 
@@ -456,7 +467,7 @@ class GammaStore:
             if i in self._inflight or i in self._prefetched:
                 return
             self._inflight.add(i)
-        self._queue.put(i)
+        self._queue.put((i, trace.current()))
 
     def prefetch_segment(self, start: int, length: int) -> None:
         """Schedule sites [start, start+length) on the worker thread."""
@@ -503,10 +514,11 @@ class GammaStore:
             self.prefetch(i + 1)
         return hit
 
-    def get_segment(self, start: int, length: int,
-                    prefetch_next_segment: bool = True):
-        """Blocking stacked read of sites [start, start+length):
-        returns (gammas (L, χ, χ, d), lambdas (L, χ)) host arrays.
+    def get_sites(self, start: int, length: int,
+                  prefetch_next_segment: bool = True
+                  ) -> tuple[list[np.ndarray], list[np.ndarray]]:
+        """Blocking read of sites [start, start+length): returns the
+        per-site Γ (χ, χ, d) and Λ (χ,) host arrays as two lists.
 
         Schedules the *next* segment on the worker before collecting this one
         so a segment-striding consumer always has the next buffer in flight.
@@ -520,6 +532,13 @@ class GammaStore:
             g, lam = self.get(i, prefetch_next=False)
             gs.append(g)
             ls.append(lam)
+        return gs, ls
+
+    def get_segment(self, start: int, length: int,
+                    prefetch_next_segment: bool = True):
+        """:meth:`get_sites` stacked: (gammas (L, χ, χ, d), lambdas
+        (L, χ)) host arrays."""
+        gs, ls = self.get_sites(start, length, prefetch_next_segment)
         return np.stack(gs), np.stack(ls)
 
     def get_segment_on_device(self, start: int, length: int,

@@ -4,10 +4,10 @@ The in-memory sampler requires the entire stacked Γ as a device operand —
 at 8,176 sites and χ=10⁴ that is impossible.  This engine splits the chain
 into fixed-size site *segments* and, while the jitted scan contracts
 segment k, a background thread reads segment k+1 from :class:`GammaStore`
-(bf16 on disk → fp32 upcast) and starts its host→device transfer
-(``device_put`` is asynchronous), so Γ I/O is hidden behind compute exactly
-as in the paper's data-parallel revival.  At most **two** segments are ever
-device-resident (current + next); consumed buffers are explicitly deleted.
+(bf16 on disk → fp32 upcast) and moves it to the device, so Γ I/O is
+hidden behind compute exactly as in the paper's data-parallel revival.  At
+most **two** segments are ever device-resident (current + next); consumed
+buffers are explicitly deleted.
 On a multi-process :class:`~repro.api.runtime.ClusterRuntime`, the same
 prefetch slot carries the paper's §3.1 collective instead: only the ROOT
 process reads the store and broadcasts each segment in storage format —
@@ -38,6 +38,15 @@ traced operand, and segment tails are padded to the segment length with
 identity site leaves the environment, its rescale factors, and every real
 site's PRNG stream untouched.
 
+Every step is a span (``repro.obs.trace``): ``engine.walk`` per walk, under
+it ``engine.wait_gamma`` (the wait on the prefetch) and ``engine.segment``
+(``engine.dispatch``, ``engine.samples_to_host``, ``engine.sync``); on the
+pool thread ``engine.fetch`` (``engine.stack``, ``engine.pad``,
+``engine.device_put``, and the store's ``store.read``/``parse``/``decode``)
+caused by the walk that submitted it.  The per-walk ``stats`` counters
+``io_wait_s``, ``compute_s``, ``fetch_s`` and ``put_s`` are the sums of
+those spans' durations, ``put_bytes`` the bytes handed to ``device_put``.
+
 Applications should reach this engine through
 :class:`repro.api.SamplingSession` (backend ``"streamed"``).
 """
@@ -46,7 +55,6 @@ from __future__ import annotations
 import dataclasses
 import os
 import threading
-import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from functools import partial
 from typing import Optional
@@ -64,6 +72,7 @@ from repro.core import sampler as S
 from repro.core.mps import MPS
 from repro.core.precision import real_dtype_of
 from repro.data import gamma_store as GS
+from repro.obs import trace
 from repro.runtime.faults import CorruptSegment, Fault
 
 
@@ -199,8 +208,15 @@ class StreamingEngine:
         if checkpoint_dir:
             os.makedirs(checkpoint_dir, exist_ok=True)
         self._pool = ThreadPoolExecutor(max_workers=1)
+        # guards the live-segment count and the pool thread's fetch totals
         self._live_lock = threading.Lock()
         self._live = 0
+        # Σ engine.fetch / engine.device_put seconds and the bytes handed to
+        # device_put, since creation; each walk reports the growth since
+        # the previous walk ended (_finish_walk), so a fetch that finishes
+        # between walks is counted once
+        self._fetched = {"fetch_s": 0.0, "put_s": 0.0, "put_bytes": 0}
+        self._fetched0 = dict(self._fetched)
         # one walk at a time: the engine is cached per plan by the session
         # and service lanes may hand it consecutive macro batches
         self._walk_lock = threading.Lock()
@@ -220,6 +236,7 @@ class StreamingEngine:
         self.stats = {"segments": 0, "io_wait_s": 0.0, "compute_s": 0.0,
                       "max_live_segments": 0, "store_io_s": 0.0,
                       "io_bytes": 0, "io_hidden_frac": 0.0,
+                      "fetch_s": 0.0, "put_s": 0.0, "put_bytes": 0,
                       "owned_segments": 0, "handoffs": 0,
                       "handoff_send_bytes": 0, "handoff_recv_bytes": 0,
                       "gather_bytes": 0, "quarantined_sites": 0,
@@ -297,41 +314,98 @@ class StreamingEngine:
                 f"processes walking the same plan?")
         return GS.decode_segment(payload, compute_dtype=self.gamma_dtype)
 
-    def _fetch(self, start: int, stop: int,
-               chi_s: int) -> tuple[jax.Array, jax.Array, int]:
+    def _fetch(self, start: int, stop: int, chi_s: int,
+               cause: Optional[trace.Span] = None
+               ) -> tuple[jax.Array, jax.Array, int]:
+        """One segment read, stacked, padded and resident on the device.
+        Runs on the pool thread under an ``engine.fetch`` span whose cause
+        is the walk that submitted it (``_submit``)."""
         L = self.plan.segment_len
-        if self.shard is not None:
-            # sharded plane: Γ NEVER crosses the interconnect — the owner
-            # reads its own slice locally (multi-process included); the
-            # walk loop schedules the next OWNED segment itself, so the
-            # blanket next-segment prefetch stays off
-            g, lam = self.store.get_segment(start, stop - start,
-                                            prefetch_next_segment=False)
-        elif self.runtime.process_count > 1:
-            g, lam = self._fetch_via_runtime(start, stop)
-        else:
-            g, lam = self.store.get_segment(start, stop - start,
-                                            prefetch_next_segment=True)
-        if chi_s < self.chi:              # §3.4.2: only the bucketed bond
-            g = g[:, :chi_s, :chi_s, :]
-            lam = lam[:, :chi_s]
-        real = g.shape[0]
-        if real < L:                      # tail: pad with identity sites
-            gp, lp = identity_sites(L - real, chi_s, self.d, g.dtype)
-            g = np.concatenate([g, gp], axis=0)
-            lam = np.concatenate([lam, lp.astype(lam.dtype)], axis=0)
-        gd, ld = jax.device_put(g), jax.device_put(lam)    # async transfer
+        with trace.span("engine.fetch", parent=cause, start=start) as fetch:
+            if self.shard is None and self.runtime.process_count > 1:
+                g, lam = self._fetch_via_runtime(start, stop)
+            else:
+                # sharded plane: Γ NEVER crosses the interconnect — the
+                # owner reads its own slice locally (multi-process
+                # included); the walk loop schedules the next OWNED segment
+                # itself, so the blanket next-segment prefetch stays off
+                gs, ls = self.store.get_sites(
+                    start, stop - start,
+                    prefetch_next_segment=self.shard is None)
+                with trace.span("engine.stack"):
+                    g, lam = np.stack(gs), np.stack(ls)
+                del gs, ls
+            if chi_s < self.chi:          # §3.4.2: only the bucketed bond
+                g = g[:, :chi_s, :chi_s, :]
+                lam = lam[:, :chi_s]
+            real = g.shape[0]
+            if real < L:                  # tail: pad with identity sites
+                with trace.span("engine.pad"):
+                    gp, lp = identity_sites(L - real, chi_s, self.d, g.dtype)
+                    g = np.concatenate([g, gp], axis=0)
+                    lam = np.concatenate([lam, lp.astype(lam.dtype)], axis=0)
+            # the walk's first kernel would wait for this transfer anyway:
+            # waiting here keeps the wait inside the fetch that causes it
+            with trace.span("engine.device_put") as put:
+                gd, ld = jax.device_put(g), jax.device_put(lam)
+                jax.block_until_ready((gd, ld))
         with self._live_lock:
             self._live += 1
             self.stats["max_live_segments"] = max(
                 self.stats["max_live_segments"], self._live)
+            self._fetched["fetch_s"] += fetch.seconds
+            self._fetched["put_s"] += put.seconds
+            self._fetched["put_bytes"] += g.nbytes + lam.nbytes
         return gd, ld, real
+
+    def _submit(self, seg: tuple[int, int, int]) -> Future:
+        """Fetch schedule entry ``seg`` on the pool thread, caused by the
+        span open here (the walk)."""
+        return self._pool.submit(self._fetch, *seg, cause=trace.current())
+
+    def _wait_gamma(self, fut: Future) -> tuple[jax.Array, jax.Array, int]:
+        """The walk's wait on the prefetch, counted in ``io_wait_s``."""
+        with trace.span("engine.wait_gamma") as sp:
+            out = fut.result()
+        self.stats["io_wait_s"] += sp.seconds
+        return out
 
     def _release(self, gd: jax.Array, ld: jax.Array) -> None:
         gd.delete()
         ld.delete()
         with self._live_lock:
             self._live -= 1
+
+    def _compute_segment(self, gd, ld, real: int, chi_s: int, env, log_scale,
+                         log_prob, key, start: int):
+        """Walk one fetched segment and release its buffers; returns
+        ``(samples (real, N), env', log_scale', log_prob')``.  Its
+        ``engine.segment`` span is counted in ``compute_s``."""
+        from repro.core.dynamic_bond import fit_env
+
+        with trace.span("engine.segment", start=start) as sp:
+            # the lock is a no-op except on the emulated cluster, where the
+            # member "processes" share one XLA backend and concurrent
+            # collective programs would interleave their rendezvous and
+            # deadlock (block_until_ready stays inside: dispatch is async)
+            with self.runtime.compute_lock():
+                with trace.span("engine.dispatch"):
+                    seg = MPS(gd, ld, self.semantics)
+                    env = fit_env(env, chi_s)  # χ-stage transition
+                    if self.clamp_map is None:
+                        samples, env, log_scale = self._run_segment(
+                            seg, env, log_scale, key, start)
+                    else:
+                        samples, env, log_scale, log_prob = \
+                            self._run_segment_clamped(seg, env, log_scale,
+                                                      log_prob, key, start)
+                with trace.span("engine.samples_to_host"):
+                    samples = np.asarray(samples[:real])  # drop pad sites
+                with trace.span("engine.sync"):
+                    jax.block_until_ready((env, log_scale))
+        self.stats["compute_s"] += sp.seconds
+        self._release(gd, ld)
+        return samples, env, log_scale, log_prob
 
     # -- one segment of the data plane --------------------------------------
     def _run_segment(self, seg: MPS, env, log_scale, key, start: int):
@@ -403,6 +477,7 @@ class StreamingEngine:
         self.stats.update(segments=0, io_wait_s=0.0, compute_s=0.0,
                           max_live_segments=live, store_io_s=0.0,
                           io_bytes=0, io_hidden_frac=0.0,
+                          fetch_s=0.0, put_s=0.0, put_bytes=0,
                           owned_segments=0, handoffs=0,
                           handoff_send_bytes=0, handoff_recv_bytes=0,
                           gather_bytes=0, quarantined_sites=0,
@@ -462,7 +537,7 @@ class StreamingEngine:
         """:meth:`sample` plus a stats snapshot taken under the walk lock —
         on a shared (session-cached) engine, reading ``self.stats`` after
         the lock drops races the next walk's reset."""
-        with self._walk_lock:
+        with self._walk_lock, trace.span("engine.walk"):
             out = self._sample_locked(n_samples, key, resume=resume,
                                       stop_after_segments=stop_after_segments,
                                       checkpoint_dir=checkpoint_dir,
@@ -472,8 +547,6 @@ class StreamingEngine:
     def _sample_locked(self, n_samples: int, key: jax.Array, *,
                        resume: bool, stop_after_segments: Optional[int],
                        checkpoint_dir, pipeline: bool) -> np.ndarray:
-        from repro.core.dynamic_bond import fit_env
-
         ckpt_dir = (self.checkpoint_dir if checkpoint_dir is self._UNSET
                     else checkpoint_dir)
         if self.clamp_map is not None and (resume or ckpt_dir):
@@ -553,42 +626,22 @@ class StreamingEngine:
 
         fut: Optional[Future] = self._take_warm(schedule[idx])
         if fut is None:
-            fut = self._pool.submit(self._fetch, *schedule[idx])
+            fut = self._submit(schedule[idx])
         seg_idx = 0
         while idx < len(schedule):
             start, _, chi_s = schedule[idx]
-            t0 = time.perf_counter()
-            gd, ld, real = fut.result()
-            self.stats["io_wait_s"] += time.perf_counter() - t0
+            gd, ld, real = self._wait_gamma(fut)
             if idx + 1 < len(schedule):   # double buffer: fetch k+1 now
-                fut = self._pool.submit(self._fetch, *schedule[idx + 1])
+                fut = self._submit(schedule[idx + 1])
             elif pipeline and stop_after_segments is None:
                 # gang-scheduling (paper §3.1 across macro batches): the
                 # pool is idle for the rest of this walk, so fetch — or on a
                 # multi-process runtime, broadcast — the next batch's FIRST
                 # segment now, behind this batch's tail compute
-                self._warm = (schedule[0],
-                              self._pool.submit(self._fetch, *schedule[0]))
+                self._warm = (schedule[0], self._submit(schedule[0]))
 
-            t0 = time.perf_counter()
-            # the lock is a no-op except on the emulated cluster, where the
-            # member "processes" share one XLA backend and concurrent
-            # collective programs would interleave their rendezvous and
-            # deadlock (block_until_ready stays inside: dispatch is async)
-            with self.runtime.compute_lock():
-                seg = MPS(gd, ld, self.semantics)
-                env = fit_env(env, chi_s)  # χ-stage transition (no-op within)
-                if self.clamp_map is None:
-                    samples, env, log_scale = self._run_segment(
-                        seg, env, log_scale, key, start)
-                else:
-                    samples, env, log_scale, log_prob = \
-                        self._run_segment_clamped(seg, env, log_scale,
-                                                  log_prob, key, start)
-                samples = np.asarray(samples[:real])  # drop identity pads
-                jax.block_until_ready((env, log_scale))
-            self.stats["compute_s"] += time.perf_counter() - t0
-            self._release(gd, ld)
+            samples, env, log_scale, log_prob = self._compute_segment(
+                gd, ld, real, chi_s, env, log_scale, log_prob, key, start)
             done.append(samples)
             self.stats["segments"] += 1
             idx += 1
@@ -702,7 +755,6 @@ class StreamingEngine:
         always durable exactly where the resume needs it, with every owned
         block below it on disk.
         """
-        from repro.core.dynamic_bond import fit_env
         from repro.shard import walk as SW
 
         if stop_after_segments is not None:
@@ -757,7 +809,7 @@ class StreamingEngine:
         if owned:
             fut = self._take_warm(schedule[owned[0]])
             if fut is None:
-                fut = self._pool.submit(self._fetch, *schedule[owned[0]])
+                fut = self._submit(schedule[owned[0]])
         next_pos = 1                      # next entry of `owned` to prefetch
 
         for idx in range(idx0, len(schedule)):
@@ -772,9 +824,9 @@ class StreamingEngine:
                 continue
 
             if incoming:                  # I take over: receive the env
-                t0 = time.perf_counter()
-                payload = self.runtime.recv(prev_owner, tag=start)
-                self.stats["io_wait_s"] += time.perf_counter() - t0
+                with trace.span("engine.wait_handoff", start=start) as sp:
+                    payload = self.runtime.recv(prev_owner, tag=start)
+                self.stats["io_wait_s"] += sp.seconds
                 env_h, ls_h, key_data, site = SW.decode_handoff(payload)
                 if site != start:
                     raise RuntimeError(
@@ -802,34 +854,18 @@ class StreamingEngine:
                         ckpt_dir, start, S.SamplerState(env, key, log_scale),
                         np.zeros((0, n_samples), dtype=np.int32), keep=0)
 
-            t0 = time.perf_counter()
-            gd, ld, real = fut.result()
-            self.stats["io_wait_s"] += time.perf_counter() - t0
+            gd, ld, real = self._wait_gamma(fut)
             if next_pos < len(owned):     # pipeline my NEXT owned segment
-                fut = self._pool.submit(self._fetch,
-                                        *schedule[owned[next_pos]])
+                fut = self._submit(schedule[owned[next_pos]])
                 next_pos += 1
             else:
                 fut = None
                 if pipeline:              # gang-schedule the next walk
-                    self._warm = (schedule[owned[0]], self._pool.submit(
-                        self._fetch, *schedule[owned[0]]))
+                    self._warm = (schedule[owned[0]],
+                                  self._submit(schedule[owned[0]]))
 
-            t0 = time.perf_counter()
-            with self.runtime.compute_lock():
-                seg = MPS(gd, ld, self.semantics)
-                env = fit_env(env, chi_s)
-                if self.clamp_map is None:
-                    samples, env, log_scale = self._run_segment(
-                        seg, env, log_scale, key, start)
-                else:
-                    samples, env, log_scale, log_prob = \
-                        self._run_segment_clamped(seg, env, log_scale,
-                                                  log_prob, key, start)
-                samples = np.asarray(samples[:real])
-                jax.block_until_ready((env, log_scale))
-            self.stats["compute_s"] += time.perf_counter() - t0
-            self._release(gd, ld)
+            samples, env, log_scale, log_prob = self._compute_segment(
+                gd, ld, real, chi_s, env, log_scale, log_prob, key, start)
             blocks[start] = samples
             self.stats["segments"] += 1
             site_done = start + real
@@ -877,10 +913,15 @@ class StreamingEngine:
                                            - self._store_q0[0])
         self.stats["repaired_sites"] = (self.store.repaired_sites
                                         - self._store_q0[1])
-        if self.stats["store_io_s"] > 0:
-            hidden = max(0.0,
-                         self.stats["store_io_s"] - self.stats["io_wait_s"])
-            self.stats["io_hidden_frac"] = hidden / self.stats["store_io_s"]
+        with self._live_lock:
+            for k, v in self._fetched.items():
+                self.stats[k] = v - self._fetched0[k]
+            self._fetched0 = dict(self._fetched)
+        # the share of the whole fetch (read, decode, stack, device_put)
+        # that the walk did not wait for
+        if self.stats["fetch_s"] > 0:
+            self.stats["io_hidden_frac"] = min(1.0, max(
+                0.0, 1.0 - self.stats["io_wait_s"] / self.stats["fetch_s"]))
         counters = self.runtime.io_counters()
         for k, v0 in self._runtime_io0.items():
             self.stats[k] = counters[k] - v0

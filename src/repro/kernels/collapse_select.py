@@ -82,6 +82,7 @@ def collapse_select(env: Array, gamma: Array, samples: Array,
     kern = functools.partial(_kernel, n_l=grid[2], d=d, out_dtype=out_dtype)
     return pl.pallas_call(
         kern,
+        name="collapse_select",  # the HLO and trace op name
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, bl), lambda i, j, k: (i, k)),

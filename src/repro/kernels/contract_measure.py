@@ -89,6 +89,7 @@ def contract_measure(env: Array, gamma: Array, lam: Array,
     kern = functools.partial(_kernel, n_l=grid[2], d=d, out_dtype=out_dtype)
     temp, probs = pl.pallas_call(
         kern,
+        name="contract_measure",  # the HLO and trace op name
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, bl), lambda i, j, k: (i, k)),

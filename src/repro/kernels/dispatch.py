@@ -47,6 +47,8 @@ from typing import Callable, Optional
 
 import jax
 
+from repro.obs import trace
+
 STAGES = ("site_step", "contract_measure", "measure", "collapse")
 KERNEL_MODES = ("auto", "pallas", "xla")
 
@@ -268,7 +270,8 @@ def autotune(stage: str, *, n: int, chi_l: int, chi_r: int, d: int,
     thunk running the kernel at ``cfg``; the fastest candidate wins and is
     cached, so a production sampler pays the sweep once per distinct
     (χ-bucket, N₂) shape.  Candidates the compiler rejects are counted
-    (``autotune_report``); when every one is rejected this raises.
+    (``autotune_report``); when every one is rejected this raises.  Each
+    tuned cell (each cache miss) is one ``dispatch.autotune`` span.
     """
     elt = jax.numpy.dtype(dtype).itemsize
     key = (stage, n, chi_l, chi_r, d, str(jax.numpy.dtype(dtype)), planes,
@@ -278,6 +281,17 @@ def autotune(stage: str, *, n: int, chi_l: int, chi_r: int, d: int,
         _stats["hits"] += 1
         return hit
     _stats["misses"] += 1
+    with trace.span("dispatch.autotune", stage=stage, n=n, chi_l=chi_l,
+                    chi_r=chi_r, d=d):
+        cfg = _tune(key, stage, n, chi_l, chi_r, d, elt, planes, probe)
+    _cache[key] = cfg
+    return cfg
+
+
+def _tune(key: tuple, stage: str, n: int, chi_l: int, chi_r: int, d: int,
+          elt: int, planes: int, probe) -> BlockConfig:
+    """The sweep (or heuristic) behind one cache miss of
+    :func:`autotune`; records the cell in ``autotune_report``."""
     rec = {"stage": stage, "n": n, "chi_l": chi_l, "chi_r": chi_r, "d": d,
            "dtype": key[5], "candidates": 1, "rejected": 0, "error": None}
     if probe is not None and on_tpu():
@@ -305,7 +319,6 @@ def autotune(stage: str, *, n: int, chi_l: int, chi_r: int, d: int,
     else:
         cfg = _heuristic(stage, n, chi_l, chi_r, d, elt, planes)
     rec["blocks"] = dataclasses.asdict(cfg)
-    _cache[key] = cfg
     _report[key] = rec
     return cfg
 

@@ -107,6 +107,7 @@ def displacement_expm(mu_re: Array, mu_im: Array, d: int,
 
     outre, outim = pl.pallas_call(
         kern,
+        name="displacement_expm",  # the HLO and trace op name
         grid=(B // bb,),
         in_specs=[pl.BlockSpec((bb,), lambda i: (i,)),
                   pl.BlockSpec((bb,), lambda i: (i,)),

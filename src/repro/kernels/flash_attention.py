@@ -110,6 +110,7 @@ def flash_attention(q: Array, k: Array, v: Array, causal: bool = True,
                              scale=scale, causal=causal)
     out = pl.pallas_call(
         kern,
+        name="flash_attention",  # the HLO and trace op name
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, dh), lambda bh, i, j: (bh, i, 0)),

@@ -240,6 +240,7 @@ def site_step_linear(env: Array, gamma: Array, lam: Array, u: Array,
         scaling=scaling, out_dtype=out_dtype, compute_dtype=compute_dtype)
     env_new, samples, dlog = pl.pallas_call(
         kern,
+        name="site_step_linear",  # the HLO and trace op name
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, bl), lambda i, j, k: (i, k)),
@@ -359,6 +360,7 @@ def site_step_born(env: Array, gamma: Array, lam: Array, u: Array,
     gt = jnp.swapaxes(gamma, 1, 2)
     ore, oim, samples, dlog = pl.pallas_call(
         kern,
+        name="site_step_born",  # the HLO and trace op name
         grid=grid,
         in_specs=[
             plane_spec, plane_spec, gamma_spec, gamma_spec,
@@ -430,6 +432,7 @@ def measure_probs(env: Array, w: Array, bn: int = 256, bl: int = 256,
                              out_dtype=out_dtype, compute_dtype=compute_dtype)
     return pl.pallas_call(
         kern,
+        name="measure_probs",  # the HLO and trace op name
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, bl), lambda i, k: (i, k)),

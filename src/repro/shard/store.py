@@ -57,14 +57,14 @@ class ShardedGammaStore(GammaStore):
         self._n_sites = int(shard.n_sites)
 
     # -- ownership enforcement ----------------------------------------------
-    def _read_raw(self, i: int):
+    def _read_raw(self, i: int, cause=None):
         if not self.shard.owns(self.host, i):
             raise ShardViolation(
                 f"host {self.host} tried to read Γ site {i}, owned by host "
                 f"{self.shard.owner(i)} (block={self.shard.block}, "
                 f"hosts={self.shard.n_hosts}) — only the (N, χ) env crosses "
                 f"hosts, never Γ")
-        return super()._read_raw(i)
+        return super()._read_raw(i, cause)
 
     def prefetch(self, i: int) -> None:
         # advisory, not a violation: blanket "schedule the next segment"

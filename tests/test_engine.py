@@ -225,3 +225,99 @@ def test_planner_micro_batch_passthrough():
     assert info["io_overlapped"] == (info["t_compute_per_site_s"]
                                      >= info["t_io_per_site_s"])
     assert info["min_macro_batch_for_overlap"] > 0
+
+
+# ---------------------------------------------------------------------------
+# Spans and counters (repro.obs.trace)
+# ---------------------------------------------------------------------------
+
+def _mine(job):
+    from repro.obs import trace
+    return [s for s in trace.spans() if s.attrs.get("job") == job]
+
+
+def test_streamed_walk_records_its_spans_and_counters(chain):
+    """10 sites in segments of 4: three fetches (the last padded), three
+    segment computes, every store read under the fetch that scheduled
+    it, and the walk's counters equal to the sums of their spans."""
+    from repro.obs import trace
+    root, mps = chain
+    trace.clear()
+    eng = StreamingEngine(_store(root), plan=StreamPlan(segment_len=4))
+    with trace.span("service.batch", job="engine-test", batch=1) as batch:
+        _, stats = eng.sample_with_stats(8, jax.random.key(0))
+    eng.close()
+    spans = _mine("engine-test")
+    named = {}
+    for s in spans:
+        named.setdefault(s.name, []).append(s)
+    (walk,) = named["engine.walk"]
+    assert walk.parent_id == batch.span_id
+    assert all(s.attrs["batch"] == 1 for s in spans)
+    for step in ("engine.wait_gamma", "engine.segment", "engine.fetch"):
+        assert len(named[step]) == 3
+        assert {s.parent_id for s in named[step]} == {walk.span_id}
+    assert sorted(s.attrs["start"] for s in named["engine.segment"]) == [
+        0, 4, 8]
+    segs = {s.span_id for s in named["engine.segment"]}
+    for step in ("engine.dispatch", "engine.samples_to_host", "engine.sync"):
+        assert len(named[step]) == 3
+        assert {s.parent_id for s in named[step]} == segs
+    fetches = {s.span_id: s for s in named["engine.fetch"]}
+    assert sorted(s.attrs["start"] for s in fetches.values()) == [0, 4, 8]
+    for step in ("engine.stack", "engine.device_put"):
+        assert len(named[step]) == 3
+        assert {s.parent_id for s in named[step]} == set(fetches)
+    (pad,) = named["engine.pad"]               # only the tail has pad sites
+    assert fetches[pad.parent_id].attrs["start"] == 8
+    for step in ("store.read", "store.parse", "store.decode"):
+        assert sorted(s.attrs["site"] for s in named[step]) == list(range(10))
+        assert {s.parent_id for s in named[step]} <= set(fetches)
+
+    def total(step):
+        return pytest.approx(sum(s.end_ns - s.start_ns
+                                 for s in named[step]) / 1e9, abs=1e-6)
+    assert stats["io_wait_s"] == total("engine.wait_gamma")
+    assert stats["compute_s"] == total("engine.segment")
+    assert stats["fetch_s"] == total("engine.fetch")
+    assert stats["put_s"] == total("engine.device_put")
+    site_bytes = mps.gammas[0].size * 8 + mps.lambdas[0].size * 8
+    assert stats["put_bytes"] == 12 * site_bytes     # 10 sites + 2 pads
+    assert stats["fetch_s"] >= stats["put_s"] > 0
+    assert stats["io_hidden_frac"] == pytest.approx(
+        min(1.0, max(0.0, 1 - stats["io_wait_s"] / stats["fetch_s"])))
+
+
+def test_service_batches_carry_the_job_down_to_the_store(chain):
+    """A two-batch job: each batch's walk sits under its ``service.batch``,
+    the consumer's waits are ``service.stream_wait``, the first walk
+    causes the gang-scheduled fetch of the second batch's first segment,
+    and the per-batch counters add up to every fetch the job made."""
+    from repro import api
+    from repro.obs import trace
+    root, _ = chain
+    trace.clear()
+    with api.SamplingService() as svc:
+        h = svc.submit(root, api.SamplerConfig(segment_len=4), n_samples=16,
+                       key=jax.random.key(1), macro_batches=2)
+        assert [b for b, _ in h.stream()] == [0, 1]
+        stats = h.stats
+    spans = _mine(h.job_id)
+    named = {}
+    for s in spans:
+        named.setdefault(s.name, []).append(s)
+    assert sorted(s.attrs["batch"] for s in named["service.stream_wait"]) \
+        == [0, 1]
+    batches = {s.attrs["batch"]: s for s in named["service.batch"]}
+    walks = {s.attrs["batch"]: s for s in named["engine.walk"]}
+    assert sorted(batches) == sorted(walks) == [0, 1]
+    for b in (0, 1):
+        assert walks[b].parent_id == batches[b].span_id
+        w = batches[b]
+        assert w.start_ns <= walks[b].start_ns <= walks[b].end_ns <= w.end_ns
+    caused = [s.parent_id for s in named["engine.fetch"]]
+    assert caused.count(walks[0].span_id) == 4       # 3 + the warm fetch
+    assert caused.count(walks[1].span_id) == 2
+    fetch_s = sum(s.end_ns - s.start_ns for s in named["engine.fetch"]) / 1e9
+    assert sum(st["fetch_s"] for st in stats.values()) == pytest.approx(
+        fetch_s, abs=1e-6)

@@ -7,12 +7,16 @@ layouts XLA and Mosaic disagree on, more VMEM than a core has.  Shapes are
 the ``jiuzhang2`` width (χ = 10⁴ padded to 10240 by ``core.mps.pad_bond``)
 at N = 4096, with d = 4 and d = 3, plus the χ/4 shard widths the
 tensor-parallel stages see on four chips.  Blocks come from the autotuner's
-heuristic, so its choices are what is compiled.
+heuristic, so its choices are what is compiled.  Each kernel must reach
+the HLO as a custom call named after its own ``pallas_call(name=...)``:
+the benchmark finds kernels in the device trace by that name.
 
 The topology is described inside a fixture (never at import): only one
 process at a time may load the TPU library, and every xdist worker imports
 this module.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -67,10 +71,18 @@ def as_tpu(monkeypatch):
     monkeypatch.setattr(site_impls, "on_tpu", lambda: True)
 
 
-def _compile(fn, *args):
+def kernel_names(text: str) -> set[str]:
+    """Instruction names (numeric suffix dropped) of the Pallas custom
+    calls in compiled HLO text."""
+    return set(re.findall(r"%([A-Za-z_][\w-]*?)(?:\.\d+)? = [^\n]*"
+                          r"custom_call_target=\"tpu_custom_call\"", text))
+
+
+def _compile(fn, *args, kernel: str):
     compiled = jax.jit(fn).lower(*args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    assert kernel in kernel_names(text), kernel_names(text)
     return compiled
 
 
@@ -88,7 +100,8 @@ def test_site_step_linear_compiles(one_chip, no_persistent_cache, d):
             e, g, lam, u, bn=cfg.bn, br=cfg.br, bl=cfg.bl,
             compute_dtype=jnp.bfloat16),
         sds((N, CHI), jnp.float32), sds((CHI, CHI, d), jnp.bfloat16),
-        sds((CHI,), jnp.float32), sds((N,), jnp.float32))
+        sds((CHI,), jnp.float32), sds((N,), jnp.float32),
+        kernel="site_step_linear")
     if d == 4:
         # Γ reaches the kernel as a bitcast of its HBM layout: no copy
         assert compiled.memory_analysis().temp_size_in_bytes == 0
@@ -103,7 +116,8 @@ def test_site_step_born_compiles(one_chip, no_persistent_cache, d):
         lambda e, g, lam, u: SS.site_step_born(e, g, lam, u, bn=cfg.bn,
                                                br=cfg.br, bl=cfg.bl),
         sds((N, CHI), jnp.complex64), sds((CHI, CHI, d), jnp.complex64),
-        sds((CHI,), jnp.float32), sds((N,), jnp.float32))
+        sds((CHI,), jnp.float32), sds((N,), jnp.float32),
+        kernel="site_step_born")
 
 
 # (χl, χr): the full bond, and the two tensor-parallel shard shapes
@@ -121,7 +135,7 @@ def test_contract_measure_compiles(one_chip, no_persistent_cache, d, chi_l,
         lambda e, g, lam: CM.contract_measure(e, g, lam, bn=cfg.bn,
                                               br=cfg.br, bl=cfg.bl),
         sds((N, chi_l), jnp.bfloat16), sds((chi_l, chi_r, d), jnp.bfloat16),
-        sds((chi_r,), jnp.float32))
+        sds((chi_r,), jnp.float32), kernel="contract_measure")
 
 
 @pytest.mark.parametrize("d", [4, 3])
@@ -134,7 +148,7 @@ def test_collapse_compiles(one_chip, no_persistent_cache, d, chi_l, chi_r):
         lambda e, g, s: CS.collapse_select(e, g, s, bn=cfg.bn, br=cfg.br,
                                            bl=cfg.bl),
         sds((N, chi_l), jnp.bfloat16), sds((chi_l, chi_r, d), jnp.bfloat16),
-        sds((N,), jnp.int32))
+        sds((N,), jnp.int32), kernel="collapse_select")
 
 
 @pytest.mark.parametrize("d", [4, 3])
@@ -146,7 +160,8 @@ def test_measure_compiles(one_chip, no_persistent_cache, d, chi_l):
     _compile(
         lambda e, w: SS.measure_probs(e, w, bn=cfg.bn, bl=cfg.bl,
                                       compute_dtype=jnp.bfloat16),
-        sds((N, chi_l), jnp.float32), sds((chi_l, d), jnp.float32))
+        sds((N, chi_l), jnp.float32), sds((chi_l, d), jnp.float32),
+        kernel="measure_probs")
 
 
 def test_streamed_segment_scan_compiles(one_chip, no_persistent_cache,
@@ -162,7 +177,7 @@ def test_streamed_segment_scan_compiles(one_chip, no_persistent_cache,
     cfg = S.SamplerConfig(compute_dtype=jnp.bfloat16, kernels="pallas")
     compiled = jax.jit(S.sample_chain, static_argnames=("config",)).lower(
         mps, state, config=cfg, start_site=sds((), jnp.int32)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert kernel_names(compiled.as_text()) == {"site_step_linear"}
 
 
 @pytest.mark.parametrize("scheme", ["tp_single", "tp_double"])
@@ -192,4 +207,4 @@ def test_tp_segment_compiles_on_2x2(topo, no_persistent_cache, as_tpu,
                        P(None, None, "model"))
         args = head + (by_left, lam, by_right, lam, start)
     compiled = f.lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert "contract_measure" in kernel_names(compiled.as_text())
