@@ -165,7 +165,7 @@ def exact_marginals(store):
 
     def sites():
         for i in range(store.n_sites):
-            g, lam = store.get_segment(i, 1, prefetch_next_segment=False)
+            g, lam = store.get_segment(i, 1)
             yield g[0], lam[0]
     return M.prefix_marginals(sites())
 

@@ -58,7 +58,7 @@ def main() -> None:
 
     # 4. bit-identical to the all-in-memory scan over the same Γ (the
     # session's §4.1 contract; "same Γ" = after the bf16 storage roundtrip)
-    g_rt, lam_rt = store.get_segment(0, sites, prefetch_next_segment=False)
+    g_rt, lam_rt = store.get_segment(0, sites)
     mps_rt = M.MPS(jnp.asarray(g_rt), jnp.asarray(lam_rt), "linear")
     with api.SamplingSession(mps_rt) as session:
         ref = session.sample(n, key)

@@ -157,8 +157,7 @@ class SamplingSession:
         with self._state_lock:
             if self._mps is None:
                 import jax.numpy as jnp
-                g, lam = self._store.get_segment(0, self.n_sites,
-                                                 prefetch_next_segment=False)
+                g, lam = self._store.get_segment(0, self.n_sites)
                 semantics = (self.config.semantics
                              if self.config.semantics != "auto" else "linear")
                 self._mps = MPS(jnp.asarray(g), jnp.asarray(lam), semantics)

@@ -7,22 +7,22 @@ paper's FP16-storage trick, halving I/O and broadcast bytes) and a one-slot
 prefetch thread; ``get(i)`` returns site i (upcast to the compute dtype) and
 immediately schedules site i+1.
 
-Three consumers build on the per-site path:
+Segments are read by :meth:`read_segment_into`, which lands each site's
+Γ payload straight from its file into a slot of a buffer the caller owns,
+in the storage format — no intermediate copy, no stack.  Three consumers
+build on it:
 
-* the all-in-memory sampler simply stacks Γ and ``lax.scan``s over it;
-* the streaming engine (``repro.engine``) walks the chain in fixed-size
-  *segments* — :meth:`prefetch_segment` schedules a whole segment on the
-  worker thread, :meth:`get_segment` blocks until it is read and returns the
-  stacked host arrays, and :meth:`get_segment_on_device` additionally hands
-  the buffers to the accelerator (``jax.device_put``) so the transfer of
-  segment k+1 overlaps the contraction of segment k;
+* the streaming engine (``repro.engine``) keeps one such segment buffer per
+  fetch thread and reuses it for every segment it streams to the device;
+* :meth:`get_segment` lands a segment in a fresh buffer and decodes it
+  (the all-in-memory sampler stacks the whole chain this way);
 * the multihost runtime (``repro.api.runtime``) broadcasts Γ in the
   **storage format**: :meth:`get_segment_raw` returns a wire payload of the
   packed on-disk bytes (bf16 when the store is bf16 — the same §3.3.2 trick
   that halves disk I/O halves the broadcast), and the module-level
   :func:`decode_segment` turns a payload back into compute-dtype arrays.
-  The local read path (:meth:`get`) decodes through the *same* function, so
-  a broadcast-received segment is bit-identical to a locally-read one.
+  Every read path decodes through the *same* function, so a
+  broadcast-received segment is bit-identical to a locally-read one.
 
 ``get(i)`` never re-reads a site whose prefetch is already in flight: it
 blocks on the worker's result queue instead (the old fall-back issued a
@@ -36,6 +36,7 @@ import io
 import json
 import os
 import queue
+import struct
 import threading
 import time
 import zipfile
@@ -45,6 +46,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from numpy.lib import format as npy_format
 
 from repro.obs import trace
 from repro.runtime.faults import CorruptSegment, Fault
@@ -55,6 +57,13 @@ from repro.runtime.faults import CorruptSegment, Fault
 #: key the serving gateway's ResultCache addresses results by.  The name
 #: deliberately does not match the ``site_*.npz`` glob.
 MANIFEST_NAME = "digests.json"
+
+#: the zip member that holds Γ, and the zip local file header before it
+#: (signature, …, file name length and extra field length at offset 26)
+GAMMA_MEMBER = "gamma.npy"
+_LOCAL_HEADER = struct.Struct("<4s22xHH")
+#: bytes per ``readinto`` of a landing payload (hashed as they pass)
+_CHUNK = 16 << 20
 
 
 def site_filename(i: int) -> str:
@@ -82,6 +91,27 @@ def merkle_root(leaves: dict[str, str]) -> str:
     for f in sorted(leaves):
         h.update(f"{f}:{leaves[f]}\n".encode())
     return h.hexdigest()
+
+
+def _npy_header(fp) -> Optional[tuple[int, tuple[int, ...], bool, np.dtype]]:
+    """(header length, shape, fortran_order, dtype) of the ``.npy`` array
+    at ``fp``'s position, leaving ``fp`` at its payload; None for a header
+    version other than 1.0 or 2.0 (:meth:`GammaStore.put` writes 1.0)."""
+    at = fp.tell()
+    read = {(1, 0): npy_format.read_array_header_1_0,
+            (2, 0): npy_format.read_array_header_2_0}.get(
+                npy_format.read_magic(fp))
+    if read is None:
+        return None
+    shape, fortran, dtype = read(fp)
+    return fp.tell() - at, shape, fortran, dtype
+
+
+def _small_member(zf: zipfile.ZipFile, name: str) -> np.ndarray:
+    """A small member (Λ, gshape, two_byte) read whole; ``zipfile`` checks
+    its CRC."""
+    return npy_format.read_array(io.BytesIO(zf.read(name)),
+                                 allow_pickle=False)
 
 
 def decode_gamma(raw: np.ndarray, gshape: tuple[int, ...], two_byte: bool,
@@ -161,6 +191,7 @@ class GammaStore:
         self.io_bytes = 0          # instrumentation for the benches
         self.io_seconds = 0.0      # worker+sync read wall time
         self.payload_reads = 0     # Γ payload reads (meta() probes excluded)
+        self.direct_reads = 0      # of those, landed straight in a slot
         self.verified_reads = 0    # payload reads digest-checked vs manifest
         self.quarantined_sites = 0
         self.repaired_sites = 0
@@ -272,10 +303,53 @@ class GammaStore:
         self._manifest = (sig, data)
         return data
 
+    def _probe_site(self, i: int) -> int:
+        """The site whose file a header probe of site i reads."""
+        return i
+
     def meta(self, i: int = 0) -> tuple[int, ...]:
         """Γ shape of site i from the npz header — no tensor payload read."""
-        with np.load(self._path(i)) as z:
+        with np.load(self._path(self._probe_site(i))) as z:
             return tuple(int(x) for x in z["gshape"])
+
+    def segment_buffer(self, length: int, i: int = 0
+                       ) -> tuple[np.ndarray, np.ndarray]:
+        """Zeroed host buffers for ``length`` sites in site i's
+        storage format, for :meth:`read_segment_into`: Γ (length, χ, χ, d)
+        in the stored dtype (``uint16`` when two_byte) and Λ (length, χ).
+        Reads the two members' ``.npy`` headers, not their payloads; a site
+        whose headers are damaged is a corrupt site, as in a read."""
+        i = self._probe_site(i)
+        fault = None
+        for _attempt in range(2):
+            with open(self._path(i), "rb") as fh:   # FileNotFoundError
+                try:                                # propagates
+                    with zipfile.ZipFile(fh) as zf:
+                        (gshape, gdt), (lshape, ldt) = [
+                            self._member_layout(zf, name)
+                            for name in (GAMMA_MEMBER, "lam.npy")]
+                    return (np.zeros((length,) + gshape, gdt),
+                            np.zeros((length,) + lshape, ldt))
+                except (zipfile.BadZipFile, ValueError, KeyError, EOFError,
+                        OSError) as e:
+                    fault = self._structural_fault(i, e)
+        self.quarantine_site(i)
+        raise CorruptSegment(fault)
+
+    @staticmethod
+    def _member_layout(zf: zipfile.ZipFile, name: str
+                       ) -> tuple[tuple[int, ...], np.dtype]:
+        with zf.open(name) as fp:
+            header = _npy_header(fp)
+        if header is None:
+            raise ValueError(f"{name}: not an .npy array of version 1.0 "
+                             f"or 2.0")
+        return tuple(header[1]), header[3]
+
+    def _structural_fault(self, i: int, e: Exception) -> Fault:
+        return Fault(kind="corruption", site=i, store=self.root,
+                     message=f"Γ site {i} is structurally corrupt "
+                             f"({type(e).__name__}: {e})")
 
     def quarantine_site(self, i: int) -> Optional[str]:
         """Move a corrupt site file aside (rename to ``*.quarantine``) so
@@ -296,8 +370,11 @@ class GammaStore:
     def _read_raw(self, i: int, cause: Optional[trace.Span] = None
                   ) -> tuple[np.ndarray, np.ndarray, tuple[int, ...], bool]:
         """One site's storage-format payload: (packed Γ, Λ, gshape, two_byte).
-        This is the only place Γ payload bytes leave the disk — the I/O
-        counters here are what the only-root-reads contract asserts on.
+        With :meth:`read_segment_into` one of the two places Γ payload
+        bytes leave the disk — the I/O counters of both are what the
+        only-root-reads contract asserts on.  This one reads the file into
+        memory and parses it with ``np.load``: the per-site :meth:`get`
+        path, and members :meth:`read_segment_into` cannot land directly.
 
         Verification happens here, at the choke point: when :attr:`verify`
         is on and the manifest carries a leaf for site i, the file bytes
@@ -339,10 +416,7 @@ class GammaStore:
                     two_byte = bool(z["two_byte"])
             except (zipfile.BadZipFile, ValueError, KeyError, EOFError,
                     OSError) as e:
-                fault = Fault(
-                    kind="corruption", site=i, store=self.root,
-                    message=f"Γ site {i} is structurally corrupt "
-                            f"({type(e).__name__}: {e})")
+                fault = self._structural_fault(i, e)
                 continue
             break
         if fault is not None:
@@ -357,6 +431,156 @@ class GammaStore:
             if checked:
                 self.verified_reads += 1
         return raw, lam, gshape, two_byte
+
+    def read_segment_into(self, start: int, stop: int,
+                          out_gamma: np.ndarray, out_lam: np.ndarray,
+                          cause: Optional[trace.Span] = None
+                          ) -> tuple[tuple[int, ...], bool]:
+        """Land sites [start, stop) in ``out_gamma[k]`` and ``out_lam[k]``
+        (site start + k), in the storage format (the ``uint16`` view when
+        two_byte; :meth:`segment_buffer` makes such buffers), and return
+        the sites' (gshape, two_byte).
+
+        A Γ member stored uncompressed in C order — what :meth:`put`
+        writes — is read straight from the file into its slot and checked
+        there against the member's CRC32; with :attr:`verify` on, the
+        manifest leaf is hashed from the same bytes as they pass.  Any
+        other member goes through :meth:`_read_raw` and is copied in.
+        Either way a site counts once in ``payload_reads`` (the direct
+        ones in ``direct_reads``), and bad bytes get the one bounded
+        re-read, quarantine and :class:`CorruptSegment` of
+        :meth:`_read_raw`.
+
+        Spans per site: ``store.parse`` (zip and ``.npy`` headers, Λ),
+        ``store.read`` (the payload), ``store.parse`` (the CRC)."""
+        n = stop - start
+        if len(out_gamma) < n or len(out_lam) < n \
+                or not out_gamma.flags.c_contiguous:
+            raise ValueError(f"segment buffers {out_gamma.shape}/"
+                             f"{out_lam.shape} cannot hold sites "
+                             f"[{start}, {stop}) contiguously")
+        layout = None
+        for k in range(n):
+            layout = self._land_site(start + k, out_gamma[k], out_lam[k],
+                                     cause)
+        return layout
+
+    def _land_site(self, i: int, g_out: np.ndarray, l_out: np.ndarray,
+                   cause: Optional[trace.Span]) -> tuple[tuple[int, ...],
+                                                        bool]:
+        """One site of :meth:`read_segment_into`, with its fault handling
+        and counters."""
+        t0 = time.perf_counter()
+        path = self._path(i)
+        fname = site_filename(i)
+        fault = None
+        checked = False
+        for _attempt in range(2):
+            expected = (self.manifest_leaves().get(fname) if self.verify
+                        else None)
+            with open(path, "rb", buffering=0) as fh:  # FileNotFoundError
+                try:                                   # propagates
+                    landed = self._land(fh, i, g_out, expected is not None,
+                                        cause)
+                except (zipfile.BadZipFile, ValueError, KeyError, EOFError,
+                        OSError) as e:
+                    fault = self._structural_fault(i, e)
+                    continue
+            if landed is None:       # compressed or Fortran-order member
+                raw, lam, gshape, two_byte = self._read_raw(i, cause)
+                np.copyto(g_out, raw, casting="no")
+                np.copyto(l_out, lam, casting="no")
+                return gshape, two_byte
+            lam, gshape, two_byte, leaf = landed
+            checked = expected is not None
+            if checked and leaf != expected:
+                fault = Fault(
+                    kind="corruption", site=i, store=self.root,
+                    message=f"Γ site {i} failed digest verification "
+                            f"against {MANIFEST_NAME} in {self.root}")
+                continue
+            fault = None
+            break
+        if fault is not None:
+            self.quarantine_site(i)
+            raise CorruptSegment(fault)
+        np.copyto(l_out, lam, casting="no")
+        with self._lock:
+            self.io_bytes += g_out.nbytes + lam.nbytes
+            self.io_seconds += time.perf_counter() - t0
+            self.payload_reads += 1
+            self.direct_reads += 1
+            if checked:
+                self.verified_reads += 1
+        return gshape, two_byte
+
+    def _land(self, fh, i: int, g_out: np.ndarray, hash_leaf: bool,
+              cause: Optional[trace.Span]):
+        """Read site i's Γ payload from ``fh`` (unbuffered) into ``g_out``
+        and check its CRC32.  Returns (Λ, gshape, two_byte, leaf digest or
+        None), or None when the member is not stored uncompressed in C
+        order with ``g_out``'s dtype and shape.  Structural damage raises
+        ``zipfile.BadZipFile``, ``ValueError``, ``EOFError`` or
+        ``OSError``."""
+        with trace.span("store.parse", parent=cause, site=i):
+            with zipfile.ZipFile(fh) as zf:       # the central directory
+                info = zf.getinfo(GAMMA_MEMBER)
+                if info.compress_type != zipfile.ZIP_STORED:
+                    return None
+                fh.seek(info.header_offset)
+                local = fh.read(_LOCAL_HEADER.size)
+                if len(local) != _LOCAL_HEADER.size:
+                    raise EOFError(f"{GAMMA_MEMBER}: local header cut short")
+                sig, n_name, n_extra = _LOCAL_HEADER.unpack(local)
+                if sig != b"PK\x03\x04" or \
+                        fh.read(n_name) != GAMMA_MEMBER.encode():
+                    raise zipfile.BadZipFile(
+                        f"{GAMMA_MEMBER}: bad local file header")
+                start = info.header_offset + _LOCAL_HEADER.size + n_name \
+                    + n_extra
+                fh.seek(start)
+                header = _npy_header(fh)
+                if header is None:
+                    return None
+                n_head, shape, fortran, dtype = header
+                if fortran or dtype != g_out.dtype \
+                        or tuple(shape) != g_out.shape:
+                    return None
+                if info.file_size != n_head + g_out.nbytes:
+                    raise zipfile.BadZipFile(
+                        f"{GAMMA_MEMBER}: {info.file_size} bytes, expected "
+                        f"{n_head + g_out.nbytes}")
+                fh.seek(start)
+                head = fh.read(n_head)
+                lam = _small_member(zf, "lam.npy")
+                gshape = tuple(int(x) for x in _small_member(zf,
+                                                             "gshape.npy"))
+                two_byte = bool(_small_member(zf, "two_byte.npy"))
+        offset = start + n_head
+        payload = memoryview(g_out.reshape(-1).view(np.uint8))
+        h = None
+        with trace.span("store.read", parent=cause, site=i):
+            if hash_leaf:            # leaf_digest over the whole file
+                h = hashlib.sha256(site_filename(i).encode())
+                fh.seek(0)
+                h.update(fh.read(offset))
+            fh.seek(offset)
+            pos = 0
+            while pos < len(payload):
+                got = fh.readinto(payload[pos:pos + _CHUNK])
+                if not got:
+                    raise EOFError(f"{GAMMA_MEMBER}: payload cut short by "
+                                   f"{len(payload) - pos} bytes")
+                if h is not None:
+                    h.update(payload[pos:pos + got])
+                pos += got
+            if h is not None:
+                h.update(fh.read())
+        with trace.span("store.parse", parent=cause, site=i):
+            crc = zlib.crc32(payload, zlib.crc32(head))
+        if crc != info.CRC:
+            raise zipfile.BadZipFile(f"Bad CRC-32 for file {GAMMA_MEMBER!r}")
+        return lam, gshape, two_byte, None if h is None else h.hexdigest()
 
     def verify_sites(self, sites=None) -> list[int]:
         """Verify site files against the digest manifest; quarantine any
@@ -469,11 +693,6 @@ class GammaStore:
             self._inflight.add(i)
         self._queue.put((i, trace.current()))
 
-    def prefetch_segment(self, start: int, length: int) -> None:
-        """Schedule sites [start, start+length) on the worker thread."""
-        for i in range(start, min(start + length, self.n_sites)):
-            self.prefetch(i)
-
     def _drain(self, block: bool) -> bool:
         """Move one worker result into ``_prefetched``; True if one arrived."""
         try:
@@ -514,41 +733,25 @@ class GammaStore:
             self.prefetch(i + 1)
         return hit
 
-    def get_sites(self, start: int, length: int,
-                  prefetch_next_segment: bool = True
-                  ) -> tuple[list[np.ndarray], list[np.ndarray]]:
-        """Blocking read of sites [start, start+length): returns the
-        per-site Γ (χ, χ, d) and Λ (χ,) host arrays as two lists.
-
-        Schedules the *next* segment on the worker before collecting this one
-        so a segment-striding consumer always has the next buffer in flight.
-        """
+    def get_segment(self, start: int, length: int
+                    ) -> tuple[np.ndarray, np.ndarray]:
+        """Blocking read of sites [start, start+length), clipped to the
+        chain end: compute-dtype (gammas (L, χ, χ, d), lambdas (L, χ))
+        host arrays, landed in a fresh buffer by
+        :meth:`read_segment_into`."""
         stop = min(start + length, self.n_sites)
-        self.prefetch_segment(start, stop - start)
-        if prefetch_next_segment:
-            self.prefetch_segment(stop, length)
-        gs, ls = [], []
-        for i in range(start, stop):
-            g, lam = self.get(i, prefetch_next=False)
-            gs.append(g)
-            ls.append(lam)
-        return gs, ls
+        raw, lam = self.segment_buffer(stop - start, start)
+        gshape, two_byte = self.read_segment_into(start, stop, raw, lam)
+        with trace.span("store.decode", start=start):
+            return decode_gamma(raw, gshape, two_byte, self.storage_dtype,
+                                self.compute_dtype), lam
 
-    def get_segment(self, start: int, length: int,
-                    prefetch_next_segment: bool = True):
-        """:meth:`get_sites` stacked: (gammas (L, χ, χ, d), lambdas
-        (L, χ)) host arrays."""
-        gs, ls = self.get_sites(start, length, prefetch_next_segment)
-        return np.stack(gs), np.stack(ls)
-
-    def get_segment_on_device(self, start: int, length: int,
-                              prefetch_next_segment: bool = True,
-                              device=None):
+    def get_segment_on_device(self, start: int, length: int, device=None):
         """Segment read + device hand-off: the returned jax arrays are already
         on (or being transferred to) the accelerator.  ``device_put`` is
         asynchronous, so callers can overlap this transfer with compute on the
         previous segment simply by calling this from a background thread."""
-        g, lam = self.get_segment(start, length, prefetch_next_segment)
+        g, lam = self.get_segment(start, length)
         return jax.device_put(g, device), jax.device_put(lam, device)
 
     def get_segment_raw(self, start: int, length: int) -> dict:
@@ -562,12 +765,8 @@ class GammaStore:
         calls this from its prefetch pool, which already overlaps the read
         and the broadcast with compute on the previous segment)."""
         stop = min(start + length, self.n_sites)
-        raws, lams, gshape, two_byte = [], [], None, False
-        for i in range(start, stop):
-            raw, lam, gshape, two_byte = self._read_raw(i)
-            raws.append(raw)
-            lams.append(lam)
-        gamma, lam = np.stack(raws), np.stack(lams)
+        gamma, lam = self.segment_buffer(stop - start, start)
+        gshape, two_byte = self.read_segment_into(start, stop, gamma, lam)
         return {"start": start, "gamma": gamma, "lam": lam, "gshape": gshape,
                 "two_byte": two_byte, "storage_dtype": self.storage_dtype,
                 "compute_dtype": self.compute_dtype,

@@ -41,11 +41,12 @@ site's PRNG stream untouched.
 Every step is a span (``repro.obs.trace``): ``engine.walk`` per walk, under
 it ``engine.wait_gamma`` (the wait on the prefetch) and ``engine.segment``
 (``engine.dispatch``, ``engine.samples_to_host``, ``engine.sync``); on the
-pool thread ``engine.fetch`` (``engine.stack``, ``engine.pad``,
-``engine.device_put``, and the store's ``store.read``/``parse``/``decode``)
-caused by the walk that submitted it.  The per-walk ``stats`` counters
-``io_wait_s``, ``compute_s``, ``fetch_s`` and ``put_s`` are the sums of
-those spans' durations, ``put_bytes`` the bytes handed to ``device_put``.
+pool thread ``engine.fetch`` (the store's ``store.parse``/``store.read``
+per site and ``store.decode``, then ``engine.pad`` and
+``engine.device_put``) caused by the walk that submitted it.  The per-walk
+``stats`` counters ``io_wait_s``, ``compute_s``, ``fetch_s`` and ``put_s``
+are the sums of those spans' durations, ``put_bytes`` the bytes handed to
+``device_put``.
 
 Applications should reach this engine through
 :class:`repro.api.SamplingSession` (backend ``"streamed"``).
@@ -85,15 +86,26 @@ class StreamPlan:
     checkpoint_every: int = 0           # segments between checkpoints; 0 = off
 
 
-def identity_sites(n: int, chi: int, d: int, dtype) -> tuple[np.ndarray,
-                                                             np.ndarray]:
-    """n pad sites that are exact no-ops for the chain walk: Γ[l,r,s] =
-    δ_lr·δ_s0 keeps the environment fixed and puts all probability mass on
-    outcome 0; Λ = 1 keeps born-semantics collapse factors at unity."""
-    g = np.zeros((n, chi, chi, d), dtype=dtype)
-    g[:, :, :, 0] = np.eye(chi)
-    lam = np.ones((n, chi), dtype=np.zeros(1, dtype).real.dtype)
-    return g, lam
+def fill_identity(g: np.ndarray, lam: np.ndarray) -> None:
+    """Make every site of ``g`` (n, χ, χ, d) and ``lam`` (n, χ) a pad site
+    that is an exact no-op for the chain walk: Γ[l,r,s] = δ_lr·δ_s0 keeps
+    the environment fixed and puts all probability mass on outcome 0;
+    Λ = 1 keeps born-semantics collapse factors at unity."""
+    g.fill(0)
+    diag = np.arange(g.shape[1])
+    g[:, diag, diag, 0] = 1
+    lam.fill(1)
+
+
+def _device_copy(x: np.ndarray) -> jax.Array:
+    """``x`` on the default device, in memory of its own.  The fetch slot
+    is overwritten by the next fetch while this segment computes, and on
+    the CPU backend ``device_put`` adopts an aligned host buffer instead of
+    copying it, so there it gets a private copy (elsewhere the transfer is
+    the copy)."""
+    if jax.default_backend() == "cpu":
+        x = np.array(x)
+    return jax.device_put(x)
 
 
 @partial(jax.jit, static_argnames=("config", "n_micro"))
@@ -208,6 +220,11 @@ class StreamingEngine:
         if checkpoint_dir:
             os.makedirs(checkpoint_dir, exist_ok=True)
         self._pool = ThreadPoolExecutor(max_workers=1)
+        # the host segment buffer each fetch lands Γ in, one per pool
+        # thread and reused by every fetch on it: a fetch returns only once
+        # its device copy is complete, so the next fetch on the same
+        # thread may overwrite it
+        self._host_segment = threading.local()
         # guards the live-segment count and the pool thread's fetch totals
         self._live_lock = threading.Lock()
         self._live = 0
@@ -227,20 +244,17 @@ class StreamingEngine:
         # (session-owned) store can serve many engines without the hidden-
         # I/O ratio mixing scopes (self.store: the sharded view when one
         # was wrapped — its counters see owned traffic only)
-        self._store_io0 = (self.store.io_seconds, self.store.io_bytes)
+        self._store0 = self._store_counters()
         # runtime counters are scoped the same way: deltas since engine
         # creation, so shared runtimes serve many engines cleanly
         self._runtime_io0 = dict(self.runtime.io_counters())
-        self._store_q0 = (self.store.quarantined_sites,
-                          self.store.repaired_sites)
         self.stats = {"segments": 0, "io_wait_s": 0.0, "compute_s": 0.0,
-                      "max_live_segments": 0, "store_io_s": 0.0,
-                      "io_bytes": 0, "io_hidden_frac": 0.0,
+                      "max_live_segments": 0, "io_hidden_frac": 0.0,
                       "fetch_s": 0.0, "put_s": 0.0, "put_bytes": 0,
                       "owned_segments": 0, "handoffs": 0,
                       "handoff_send_bytes": 0, "handoff_recv_bytes": 0,
-                      "gather_bytes": 0, "quarantined_sites": 0,
-                      "repaired_sites": 0}
+                      "gather_bytes": 0,
+                      **{k: 0 for k in self._STORE_COUNTERS}}
         for k in self._runtime_io0:
             self.stats[k] = 0
         # the shard algebra must hold for the REAL schedule (χ-stages can
@@ -274,6 +288,17 @@ class StreamingEngine:
         # (shardmap.chain_segments) so "every segment has one owner" is
         # proved against the very schedule this engine walks
         return chain_segments(self.n_sites, self.plan.segment_len, stages)
+
+    #: per-walk stats read off the store: stats key → store attribute
+    _STORE_COUNTERS = {"store_io_s": "io_seconds", "io_bytes": "io_bytes",
+                       "payload_reads": "payload_reads",
+                       "direct_reads": "direct_reads",
+                       "quarantined_sites": "quarantined_sites",
+                       "repaired_sites": "repaired_sites"}
+
+    def _store_counters(self) -> dict:
+        return {k: getattr(self.store, a)
+                for k, a in self._STORE_COUNTERS.items()}
 
     # -- segment fetch (runs on the pool thread) ----------------------------
     def _fetch_via_runtime(self, start: int,
@@ -314,40 +339,53 @@ class StreamingEngine:
                 f"processes walking the same plan?")
         return GS.decode_segment(payload, compute_dtype=self.gamma_dtype)
 
+    def _land(self, start: int, stop: int) -> tuple[np.ndarray, np.ndarray]:
+        """Sites [start, stop) read into this thread's host segment buffer
+        (allocated at its first fetch), decoded in one call: compute-dtype
+        (L, χ, χ, d) and (L, χ) arrays — views of the buffer when the store
+        holds the compute dtype.  Slots past ``stop - start`` hold zeros or
+        an earlier segment's sites."""
+        buf = getattr(self._host_segment, "buf", None)
+        if buf is None:
+            buf = self._host_segment.buf = self.store.segment_buffer(
+                self.plan.segment_len, start)
+        raw, lam = buf
+        gshape, two_byte = self.store.read_segment_into(start, stop, raw, lam)
+        with trace.span("store.decode", start=start):
+            return GS.decode_gamma(raw, gshape, two_byte,
+                                   self.store.storage_dtype,
+                                   self.gamma_dtype), lam
+
     def _fetch(self, start: int, stop: int, chi_s: int,
                cause: Optional[trace.Span] = None
                ) -> tuple[jax.Array, jax.Array, int]:
-        """One segment read, stacked, padded and resident on the device.
-        Runs on the pool thread under an ``engine.fetch`` span whose cause
-        is the walk that submitted it (``_submit``)."""
+        """One segment read, padded and resident on the device.  Runs on
+        the pool thread under an ``engine.fetch`` span whose cause is the
+        walk that submitted it (``_submit``)."""
         L = self.plan.segment_len
+        real = stop - start
         with trace.span("engine.fetch", parent=cause, start=start) as fetch:
             if self.shard is None and self.runtime.process_count > 1:
                 g, lam = self._fetch_via_runtime(start, stop)
             else:
-                # sharded plane: Γ NEVER crosses the interconnect — the
-                # owner reads its own slice locally (multi-process
-                # included); the walk loop schedules the next OWNED segment
-                # itself, so the blanket next-segment prefetch stays off
-                gs, ls = self.store.get_sites(
-                    start, stop - start,
-                    prefetch_next_segment=self.shard is None)
-                with trace.span("engine.stack"):
-                    g, lam = np.stack(gs), np.stack(ls)
-                del gs, ls
+                # local read (the sharded plane included: the owner reads
+                # its own slice, and Γ never crosses the interconnect)
+                g, lam = self._land(start, stop)
             if chi_s < self.chi:          # §3.4.2: only the bucketed bond
                 g = g[:, :chi_s, :chi_s, :]
                 lam = lam[:, :chi_s]
-            real = g.shape[0]
             if real < L:                  # tail: pad with identity sites
                 with trace.span("engine.pad"):
-                    gp, lp = identity_sites(L - real, chi_s, self.d, g.dtype)
-                    g = np.concatenate([g, gp], axis=0)
-                    lam = np.concatenate([lam, lp.astype(lam.dtype)], axis=0)
+                    if len(g) < L:        # a broadcast payload: real sites
+                        g = np.concatenate([g, np.empty(
+                            (L - real,) + g.shape[1:], g.dtype)])
+                        lam = np.concatenate([lam, np.empty(
+                            (L - real,) + lam.shape[1:], lam.dtype)])
+                    fill_identity(g[real:], lam[real:])
             # the walk's first kernel would wait for this transfer anyway:
             # waiting here keeps the wait inside the fetch that causes it
             with trace.span("engine.device_put") as put:
-                gd, ld = jax.device_put(g), jax.device_put(lam)
+                gd, ld = _device_copy(g), _device_copy(lam)
                 jax.block_until_ready((gd, ld))
         with self._live_lock:
             self._live += 1
@@ -468,20 +506,17 @@ class StreamingEngine:
         """Re-anchor the I/O deltas and zero the per-walk stats: a cached
         engine serves many macro batches, but ``stats`` always describes
         the most recent walk (the pre-cache contract)."""
-        self._store_io0 = (self.store.io_seconds, self.store.io_bytes)
-        self._store_q0 = (self.store.quarantined_sites,
-                          self.store.repaired_sites)
+        self._store0 = self._store_counters()
         self._runtime_io0 = dict(self.runtime.io_counters())
         with self._live_lock:
             live = self._live           # a warm prefetched segment counts
         self.stats.update(segments=0, io_wait_s=0.0, compute_s=0.0,
-                          max_live_segments=live, store_io_s=0.0,
-                          io_bytes=0, io_hidden_frac=0.0,
+                          max_live_segments=live, io_hidden_frac=0.0,
                           fetch_s=0.0, put_s=0.0, put_bytes=0,
                           owned_segments=0, handoffs=0,
                           handoff_send_bytes=0, handoff_recv_bytes=0,
-                          gather_bytes=0, quarantined_sites=0,
-                          repaired_sites=0)
+                          gather_bytes=0,
+                          **{k: 0 for k in self._STORE_COUNTERS})
         for k in self._runtime_io0:
             self.stats[k] = 0
         self.stats.pop("log_prob", None)   # set per walk, clamped only
@@ -907,17 +942,13 @@ class StreamingEngine:
         """Fold the store's and the runtime's I/O counters (deltas since
         engine creation) into ``stats`` and line the processes up — every
         process finishes macro batch b before any starts b+1."""
-        self.stats["store_io_s"] = self.store.io_seconds - self._store_io0[0]
-        self.stats["io_bytes"] = self.store.io_bytes - self._store_io0[1]
-        self.stats["quarantined_sites"] = (self.store.quarantined_sites
-                                           - self._store_q0[0])
-        self.stats["repaired_sites"] = (self.store.repaired_sites
-                                        - self._store_q0[1])
+        for k, v in self._store_counters().items():
+            self.stats[k] = v - self._store0[k]
         with self._live_lock:
             for k, v in self._fetched.items():
                 self.stats[k] = v - self._fetched0[k]
             self._fetched0 = dict(self._fetched)
-        # the share of the whole fetch (read, decode, stack, device_put)
+        # the share of the whole fetch (read, decode, pad, device_put)
         # that the walk did not wait for
         if self.stats["fetch_s"] > 0:
             self.stats["io_hidden_frac"] = min(1.0, max(

@@ -3,9 +3,9 @@
 The acceptance contract for the sharded data plane is "no host read or
 received a Γ segment it does not own".  Rather than asserting that after
 the fact, the store refuses up front: :meth:`ShardedGammaStore._read_raw`
-— the single choke point through which every Γ payload byte leaves disk —
-raises :class:`ShardViolation` for a foreign site *before* touching the
-file.  The engine's sharded walk therefore cannot silently fall back to
+and :meth:`ShardedGammaStore.read_segment_into` — the two ways a Γ
+payload byte leaves disk — raise :class:`ShardViolation` for a foreign site
+*before* touching the file.  The engine's sharded walk therefore cannot silently fall back to
 reading a neighbour's sites, and the per-engine ``io_bytes``/
 ``payload_reads`` counters count owned traffic only, by construction.
 
@@ -57,18 +57,29 @@ class ShardedGammaStore(GammaStore):
         self._n_sites = int(shard.n_sites)
 
     # -- ownership enforcement ----------------------------------------------
-    def _read_raw(self, i: int, cause=None):
+    def _check_read(self, i: int) -> None:
         if not self.shard.owns(self.host, i):
             raise ShardViolation(
                 f"host {self.host} tried to read Γ site {i}, owned by host "
                 f"{self.shard.owner(i)} (block={self.shard.block}, "
                 f"hosts={self.shard.n_hosts}) — only the (N, χ) env crosses "
                 f"hosts, never Γ")
+
+    def _read_raw(self, i: int, cause=None):
+        self._check_read(i)
         return super()._read_raw(i, cause)
 
+    def read_segment_into(self, start: int, stop: int, out_gamma, out_lam,
+                          cause=None):
+        # every site is checked before the first byte of any is read
+        for i in range(start, stop):
+            self._check_read(i)
+        return super().read_segment_into(start, stop, out_gamma, out_lam,
+                                         cause)
+
     def prefetch(self, i: int) -> None:
-        # advisory, not a violation: blanket "schedule the next segment"
-        # calls from the shared walk code may overrun an ownership boundary
+        # advisory, not a violation: get(i) schedules i + 1, which may lie
+        # across an ownership boundary
         if self.shard.owns(self.host, i):
             super().prefetch(i)
 
@@ -80,27 +91,29 @@ class ShardedGammaStore(GammaStore):
         super().put(i, gamma, lam)
         self._n_sites = int(self.shard.n_sites)   # global, not file count
 
-    def meta(self, i: int = 0):
-        """Shape probe (header only, no payload read).  A foreign site
-        redirects to this host's first owned site — chains stream through
-        one fixed (χ, χ, d) site shape, which is what callers probe for."""
-        if not self.shard.owns(self.host, i):
-            owned = self.shard.owned_sites(self.host)
-            if not owned:
-                raise ShardViolation(
-                    f"host {self.host} owns no sites of the "
-                    f"{self.shard.n_sites}-site chain "
-                    f"(block={self.shard.block} × {self.shard.n_hosts} "
-                    f"hosts) and cannot probe a site shape")
-            i = owned[0]
-        return super().meta(i)
+    def _probe_site(self, i: int) -> int:
+        """Header probes (:meth:`meta`, :meth:`segment_buffer`) of a
+        foreign site read this host's first owned site instead — chains
+        stream through one fixed (χ, χ, d) site format, which is what
+        callers probe for."""
+        if self.shard.owns(self.host, i):
+            return i
+        owned = self.shard.owned_sites(self.host)
+        if not owned:
+            raise ShardViolation(
+                f"host {self.host} owns no sites of the "
+                f"{self.shard.n_sites}-site chain "
+                f"(block={self.shard.block} × {self.shard.n_hosts} "
+                f"hosts) and cannot probe a site shape")
+        return owned[0]
 
     # -- global digest from a slice -----------------------------------------
     def digest(self) -> str:
         """The WHOLE store's Merkle root, computed from this host's owned
         leaves plus the manifest's (or, on a shared root with no manifest,
         by hashing the present foreign files directly — a metadata read,
-        not a Γ payload read; the enforcement path is :meth:`_read_raw`)."""
+        not a Γ payload read; payload reads are what the ownership check
+        guards)."""
         if self._digest is None:
             owned_leaves = self.site_digests()
             manifest = {}

@@ -15,7 +15,7 @@ from repro.core.perfmodel import Hardware, Workload, choose_tp_scheme
 from repro.data.gamma_store import GammaStore
 from repro.engine import (StreamPlan, StreamingEngine, explain_plan,
                           plan_stream)
-from repro.engine.streaming import identity_sites
+from repro.engine.streaming import fill_identity
 from repro.runtime.elastic import WorkQueue
 
 
@@ -170,9 +170,77 @@ def test_multihost_engine_root_reads_peers_receive(chain):
     assert stats[1]["broadcast_segments"] == stats[1]["segments"]
 
 
+def _count_segment_buffers(store):
+    """Wrap ``store.segment_buffer`` to keep every buffer it allocates."""
+    made = []
+    alloc = store.segment_buffer
+
+    def segment_buffer(*args):
+        made.append(alloc(*args))
+        return made[-1]
+    store.segment_buffer = segment_buffer
+    return made
+
+
+@pytest.mark.parametrize("storage", ["float64", "bfloat16"])
+def test_pipelined_batches_reuse_the_host_segment(chain, tmp_path, storage):
+    """Two pipelined macro batches on one engine (the second's first
+    segment fetched behind the first's tail; the 10-site chain's last
+    segment of 4 padded over stale slots) give the samples of a fresh
+    engine per batch, through one host segment buffer."""
+    root, mps = chain
+    if storage == "bfloat16":
+        root = str(tmp_path / "bf16")
+        with GammaStore(root, storage_dtype=jnp.bfloat16,
+                        compute_dtype=jnp.float32) as st:
+            st.write_mps(mps)
+
+    def store():
+        return GammaStore(root, storage_dtype=getattr(jnp, storage),
+                          compute_dtype=jnp.float64 if storage == "float64"
+                          else jnp.float32)
+    keys = [jax.random.fold_in(jax.random.key(5), b) for b in (0, 1)]
+    shared = store()
+    made = _count_segment_buffers(shared)
+    with StreamingEngine(shared, plan=StreamPlan(segment_len=4)) as eng:
+        outs = [eng.sample(8, k, pipeline=True) for k in keys]
+        assert len(made) == 1 and made[0][0].shape[0] == 4
+    for k, out in zip(keys, outs):
+        with StreamingEngine(store(), plan=StreamPlan(segment_len=4)) as eng:
+            assert np.array_equal(out, eng.sample(8, k))
+
+
+def test_fetched_segment_owns_its_device_memory(chain):
+    """Overwriting the host segment buffer after a fetch — what the next
+    fetch does while this segment computes — leaves the fetched device
+    arrays as they were, also where the CPU backend would adopt an aligned
+    host buffer instead of copying it."""
+    from repro.engine.streaming import _device_copy
+    root, _ = chain
+    store = _store(root)
+    made = _count_segment_buffers(store)
+    with StreamingEngine(store, plan=StreamPlan(segment_len=4)) as eng:
+        gd, ld, real = eng._fetch(0, 4, eng.chi)
+        want = np.array(gd), np.array(ld)
+        for buf in made[0]:
+            buf.view(np.uint8).fill(0x7F)
+        assert real == 4
+        assert np.array_equal(np.asarray(gd), want[0])
+        assert np.array_equal(np.asarray(ld), want[1])
+        eng._release(gd, ld)
+    raw = np.empty(4096 + 64, np.uint8)
+    at = -raw.ctypes.data % 64
+    x = raw[at:at + 4096].view(np.float64)
+    x.fill(1.0)
+    xd = _device_copy(x)
+    x.fill(2.0)
+    assert np.all(np.asarray(xd) == 1.0)
+
+
 def test_identity_pad_sites_are_noops():
-    g, lam = identity_sites(2, 4, 3, np.float64)
-    assert g.shape == (2, 4, 4, 3) and lam.shape == (2, 4)
+    g, lam = np.full((2, 4, 4, 3), 7.0), np.full((2, 4), 7.0)
+    fill_identity(g, lam)                 # in place, over stale sites
+    np.testing.assert_array_equal(lam, 1.0)
     env = np.array([[0.2, 0.5, 0.1, 0.0]])
     temp = np.einsum("nl,lrs->nrs", env, g[0])
     np.testing.assert_array_equal(temp[:, :, 0], env)   # outcome 0 = identity
@@ -265,14 +333,20 @@ def test_streamed_walk_records_its_spans_and_counters(chain):
         assert {s.parent_id for s in named[step]} == segs
     fetches = {s.span_id: s for s in named["engine.fetch"]}
     assert sorted(s.attrs["start"] for s in fetches.values()) == [0, 4, 8]
-    for step in ("engine.stack", "engine.device_put"):
-        assert len(named[step]) == 3
+    assert "engine.stack" not in named         # sites land in place
+    for step in ("store.decode", "engine.device_put"):
+        assert len(named[step]) == 3           # one per segment
         assert {s.parent_id for s in named[step]} == set(fetches)
     (pad,) = named["engine.pad"]               # only the tail has pad sites
     assert fetches[pad.parent_id].attrs["start"] == 8
-    for step in ("store.read", "store.parse", "store.decode"):
-        assert sorted(s.attrs["site"] for s in named[step]) == list(range(10))
-        assert {s.parent_id for s in named[step]} <= set(fetches)
+    # per site: the payload read, and the header lookup and CRC check
+    reads = sorted(s.attrs["site"] for s in named["store.read"])
+    assert reads == list(range(10))
+    assert sorted(s.attrs["site"] for s in named["store.parse"]) == sorted(
+        2 * reads)
+    for step in ("store.read", "store.parse"):
+        assert {s.parent_id for s in named[step]} == set(fetches)
+    assert stats["payload_reads"] == stats["direct_reads"] == 10
 
     def total(step):
         return pytest.approx(sum(s.end_ns - s.start_ns

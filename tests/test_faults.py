@@ -47,6 +47,7 @@ from repro.api.remote import execute_payload
 from repro.api.service import SamplingService
 from repro.data import gamma_store as GS
 from repro.data.gamma_store import GammaStore
+from repro.engine import StreamPlan, StreamingEngine
 from repro.runtime import transport
 from repro.runtime.elastic import WorkQueue
 from repro.runtime.faults import (KINDS, CorruptSegment, CrashLoopLane,
@@ -308,14 +309,39 @@ def test_segment_payload_crc_rejected(chain):
 # verified Γ I/O: detect, quarantine
 # ---------------------------------------------------------------------------
 
-def test_bitflip_detected_and_quarantined(chain, tmp_path):
+#: the three ways a segment's Γ leaves the store: the per-site ``get``
+#: (``np.load``), ``get_segment`` and the streaming engine's fetch (both
+#: land each site straight in a segment buffer)
+READERS = ("get", "get_segment", "engine_fetch")
+
+
+def _read_segment(store, start, stop, via):
+    if via == "get":
+        for i in range(start, stop):
+            store.get(i, prefetch_next=False)
+    elif via == "get_segment":
+        store.get_segment(start, stop - start)
+    else:
+        eng = StreamingEngine(store, plan=StreamPlan(segment_len=2))
+        try:
+            gd, ld, _ = eng._fetch(start, stop, eng.chi)
+            eng._release(gd, ld)
+        finally:
+            eng.close(close_store=False)
+
+
+@pytest.mark.parametrize("start", [2, 3])
+@pytest.mark.parametrize("via", READERS)
+def test_bitflip_detected_and_quarantined(chain, tmp_path, via, start):
+    """Site 3 rotted, read in a segment that starts before it or at it
+    (where the segment buffer's header probe meets the damage first)."""
     root = _copy_store(chain, str(tmp_path / "rot"))
     _flip_bytes(_site_path(root, 3))
     with GammaStore(root, storage_dtype=jnp.float64,
                     compute_dtype=jnp.float64) as store:
         # single host, verify off: the structural npz catch still fires
         with pytest.raises(CorruptSegment) as ei:
-            store.get_segment(2, 2)
+            _read_segment(store, start, 4, via)
         f = ei.value.fault
         assert f.kind == "corruption" and f.site == 3 and f.store == root
         assert store.quarantined_sites == 1
@@ -323,33 +349,39 @@ def test_bitflip_detected_and_quarantined(chain, tmp_path):
     assert os.path.exists(_site_path(root, 3) + ".quarantine")
 
 
-def test_digest_mismatch_detected_when_verify_on(chain, tmp_path):
+@pytest.mark.parametrize("via", READERS)
+def test_digest_mismatch_detected_when_verify_on(chain, tmp_path, via):
     root = _copy_store(chain, str(tmp_path / "stale"))
     with GammaStore(root, storage_dtype=jnp.float64,
                     compute_dtype=jnp.float64, verify=True) as store:
         g, lam = store.get(0, prefetch_next=False)   # healthy: verified read
-        assert store.verified_reads >= 1
+        _read_segment(store, 0, 2, via)
+        assert store.verified_reads >= 3
         # overwrite site 2 with a structurally VALID but different file —
         # only the manifest digest can catch this
         np.savez(_site_path(root, 2), gamma=np.zeros_like(g),
                  gshape=np.array(g.shape), lam=np.zeros_like(lam),
                  two_byte=np.array(False))
         with pytest.raises(CorruptSegment) as ei:
-            store.get(2, prefetch_next=False)
-        assert ei.value.fault.kind == "corruption"
-        assert "digest" in ei.value.fault.message
+            _read_segment(store, 2, 3, via)
+        f = ei.value.fault
+        assert f.kind == "corruption" and f.site == 2 and f.store == root
+        assert "digest" in f.message
     assert os.path.exists(_site_path(root, 2) + ".quarantine")
 
 
-def test_truncated_site_detected(chain, tmp_path):
+@pytest.mark.parametrize("via", READERS)
+def test_truncated_site_detected(chain, tmp_path, via):
     root = _copy_store(chain, str(tmp_path / "torn"))
     path = _site_path(root, 5)
     with open(path, "r+b") as f:
         f.truncate(os.path.getsize(path) // 2)
     with GammaStore(root, storage_dtype=jnp.float64,
                     compute_dtype=jnp.float64) as store:
-        with pytest.raises(CorruptSegment):
-            store.get(5, prefetch_next=False)
+        with pytest.raises(CorruptSegment) as ei:
+            _read_segment(store, 4, 6, via)
+        f = ei.value.fault
+        assert f.kind == "corruption" and f.site == 5 and f.store == root
     assert os.path.exists(path + ".quarantine")
 
 

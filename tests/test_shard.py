@@ -139,14 +139,32 @@ def test_foreign_read_raises_prefetch_is_advisory(chain):
         with pytest.raises(ShardViolation, match="owned by host 1"):
             view.get(2, prefetch_next=False)
         with pytest.raises(ShardViolation):
-            view.get_segment(2, 2, prefetch_next_segment=False)
+            view.get_segment(2, 2)
         # blanket prefetch over a boundary is skipped, not fatal
-        view.prefetch(3)
-        view.prefetch_segment(0, 4)
+        for i in (3, 0, 1, 2):
+            view.prefetch(i)
         g2, _ = view.get(1, prefetch_next=False)   # still healthy after
         assert g2.shape == (6, 6, 3)
         with pytest.raises(ShardViolation, match="write"):
             view.put(2, np.zeros((6, 6, 3)), np.zeros(6))
+
+
+def test_segment_landing_refuses_foreign_sites_before_reading(chain):
+    """``read_segment_into`` checks every site of the segment before it
+    reads any: a segment that runs into a foreign site raises
+    ShardViolation with no payload byte read and the buffer untouched."""
+    root, _ = chain
+    sm = ShardMap(n_sites=10, n_hosts=2, block=2)
+    with ShardedGammaStore(root, sm, host=0, storage_dtype=jnp.float64,
+                           compute_dtype=jnp.float64) as view:
+        g, lam = view.segment_buffer(2, 2)   # probe redirects to site 0
+        g.fill(-1.0)
+        with pytest.raises(ShardViolation, match="site 2, owned by host 1"):
+            view.read_segment_into(1, 3, g, lam)
+        assert view.payload_reads == view.io_bytes == 0
+        assert np.all(g == -1.0)
+        view.read_segment_into(0, 2, g, lam)      # owned: lands directly
+        assert view.direct_reads == view.payload_reads == 2
 
 
 def test_meta_redirects_and_empty_host_raises(chain, tmp_path):
